@@ -36,7 +36,7 @@ import jax
 
 from repro.core import AdaptiveBatchController, make_policy
 from repro.data import sigmoid_synthetic
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.elastic import MeshLadder
 from repro.models import small
 from repro.optim import sgd
@@ -69,7 +69,7 @@ def _train(mode: str, *, n: int, d: int, m0: int, m_max: int, granule: int,
     if mode == "elastic":
         ladder = MeshLadder(devices, granule=granule)
     elif mode == "fixed":
-        mesh = jax.make_mesh((len(devices),), ("data",))
+        mesh = make_mesh((len(devices),), ("data",))
         ctx = use_plan(ShardingPlan(mesh=mesh))
     else:
         raise ValueError(mode)

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import attention as kattn
-from repro.kernels import ops, psgn, ref
+from repro.kernels import default_interpret, ops, psgn, ref
 from repro.kernels.quant import quantize_int8
 from repro.models import attention as attn_lib
 
@@ -215,7 +215,7 @@ def run(smoke: bool = False, out_path: str | None = None):
     record = {
         "workload": {
             "task": "kernel-lane-microbench", "smoke": smoke,
-            "interpret": ops.default_interpret(),
+            "interpret": default_interpret(),
             "backend": jax.default_backend(),
         },
         "paged_decode": paged_record,

@@ -53,7 +53,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.batch_policy import num_buckets
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.elastic import MeshLadder
 from repro.models import transformer as tf
 from repro.serve import POLICIES, Request, ServeEngine, padded_prompt_len
@@ -117,7 +117,7 @@ def _serve(mode: str, smoke: bool, policy: str = "fifo"):
     if mode == "elastic":
         ladder = MeshLadder(devices, granule=1)
     elif mode == "fixed":
-        mesh = jax.make_mesh((len(devices),), ("data",))
+        mesh = make_mesh((len(devices),), ("data",))
         ctx = use_plan(ShardingPlan(mesh=mesh, tp=None))
     else:
         raise ValueError(mode)

@@ -1,72 +1,60 @@
 """Benchmark harness: one module per paper table/figure.
-Prints ``name,us_per_call,derived`` CSV rows."""
+Prints ``name,us_per_call,derived`` CSV rows.
+
+Each bench runs in a process of its own, and this parent never imports
+JAX: an accelerator belongs to one process at a time, and the elastic/serve
+benches force a multi-device CPU harness that must not leak into the
+others.  ``--one MODULE`` is the child side: it runs that bench's ``run()``
+in-process and prints its rows.
+"""
 
 from __future__ import annotations
 
+import argparse
+import importlib
 import subprocess
 import sys
-import traceback
+
+MODULES = [
+    ("engine", "bench_engine"),
+    ("adapt", "bench_adapt"),
+    ("elastic", "bench_elastic"),
+    ("serve", "bench_serve"),
+    ("synthetic(fig1/2)", "bench_synthetic"),
+    ("table1", "bench_table1"),
+    ("table2(memory)", "bench_table2_memory"),
+    ("estimators", "bench_estimators"),
+    ("kernels", "bench_kernels"),
+    ("roofline", "roofline"),
+]
 
 
-def _subprocess_module(module: str):
-    """bench_elastic/bench_serve force an 8-device CPU harness pre-jax-init,
-    which must not leak into the other benches' (default-device)
-    measurements — each gets its own process, exactly like the CI
-    invocation."""
-
-    def run():
-        proc = subprocess.run(
-            [sys.executable, "-m", module],
-            capture_output=True, text=True,
-        )
-        if proc.returncode:
-            sys.stderr.write(proc.stderr)  # surface the child's actual error
-            raise RuntimeError(
-                f"{module} subprocess failed (exit {proc.returncode})"
-            )
-        for line in proc.stdout.splitlines():
-            parts = line.split(",", 2)
-            if len(parts) == 3:
-                name, us, derived = parts
-                yield name, float(us), derived
-
-    return type("_SubprocessModule", (), {"run": staticmethod(run)})
+def run_one(module: str) -> None:
+    mod = importlib.import_module(f"benchmarks.{module}")
+    for name, us, derived in mod.run():
+        print(f"{name},{us:.1f},{derived}", flush=True)
 
 
 def main() -> None:
-    from benchmarks import (
-        bench_adapt,
-        bench_engine,
-        bench_estimators,
-        bench_kernels,
-        bench_synthetic,
-        bench_table1,
-        bench_table2_memory,
-        roofline,
-    )
-
-    modules = [
-        ("engine", bench_engine),
-        ("adapt", bench_adapt),
-        ("elastic", _subprocess_module("benchmarks.bench_elastic")),
-        ("serve", _subprocess_module("benchmarks.bench_serve")),
-        ("synthetic(fig1/2)", bench_synthetic),
-        ("table1", bench_table1),
-        ("table2(memory)", bench_table2_memory),
-        ("estimators", bench_estimators),
-        ("kernels", bench_kernels),
-        ("roofline", roofline),
-    ]
-    print("name,us_per_call,derived")
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--one", default=None, metavar="MODULE",
+                    help="run a single bench module in this process")
+    args = ap.parse_args()
+    if args.one:
+        run_one(args.one)
+        return
+    print("name,us_per_call,derived", flush=True)
     failed = 0
-    for label, mod in modules:
-        try:
-            for name, us, derived in mod.run():
-                print(f"{name},{us:.1f},{derived}")
-        except Exception as e:  # noqa: BLE001
+    for label, module in MODULES:
+        proc = subprocess.run(
+            [sys.executable, "-m", "benchmarks.run", "--one", module],
+            capture_output=True, text=True,
+        )
+        sys.stdout.write(proc.stdout)
+        if proc.returncode:
             failed += 1
-            print(f"{label},0,ERROR:{type(e).__name__}:{e}", file=sys.stdout)
-            traceback.print_exc(file=sys.stderr)
+            sys.stderr.write(proc.stderr)  # surface the child's actual error
+            print(f"{label},0,ERROR:exit {proc.returncode}")
     if failed:
         sys.exit(1)
 

@@ -30,6 +30,7 @@ from repro.models import small
 from repro.obs import RunLog, Tracer
 from repro.optim import sgd
 from repro.train.loop import ModelFns, Trainer
+from repro.utils.xla_env import enable_compile_cache
 
 
 def make_program():
@@ -46,6 +47,7 @@ def make_program():
 
 
 def main():
+    enable_compile_cache()
     # 1. data + model (the paper's eq. 3 synthetic task)
     train, val, _ = sigmoid_synthetic(n=8000, d=128, seed=0)
     params = small.logreg_init(jax.random.key(0), 128)

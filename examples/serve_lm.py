@@ -22,12 +22,14 @@ from repro.configs import get_config
 from repro.elastic import MeshLadder
 from repro.models import transformer as tf
 from repro.serve import POLICIES, Request, ServeEngine
+from repro.utils.xla_env import enable_compile_cache
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="fifo", choices=list(POLICIES))
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("yi-6b", reduced=True).replace(num_layers=4, d_model=128,
                                                     num_heads=4, num_kv_heads=2)

@@ -19,6 +19,7 @@ from repro.data import sigmoid_synthetic
 from repro.models import small
 from repro.optim import sgd
 from repro.train.loop import ModelFns, Trainer
+from repro.utils.xla_env import enable_compile_cache
 
 
 def run_method(task, method, estimator, *, n, d, epochs, delta, m0, m_max, lr, seed):
@@ -48,6 +49,7 @@ def main():
                     help="paper-scale n=20000, d=512, 100 epochs (slow on CPU)")
     ap.add_argument("--out", default="runs/synthetic_convex.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     scale = dict(n=20_000, d=512, epochs=100) if args.full else dict(n=4000, d=128, epochs=15)
     results = {}

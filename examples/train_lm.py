@@ -38,6 +38,7 @@ from repro.models import transformer as tf
 from repro.optim import sgd
 from repro.train import StepEngine, init_state
 from repro.ckpt import CheckpointManager
+from repro.utils.xla_env import enable_compile_cache
 
 
 def model_config(big: bool) -> ModelConfig:
@@ -72,6 +73,7 @@ def main():
     ap.add_argument("--lr", type=float, default=0.3)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = model_config(args.model_100m)
     params = tf.init_params(cfg, jax.random.key(0))

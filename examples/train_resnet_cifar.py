@@ -19,6 +19,7 @@ from repro.data import ArrayDataset, imagelike_classification
 from repro.models import resnet
 from repro.optim import sgd
 from repro.train.loop import ModelFns, Trainer
+from repro.utils.xla_env import enable_compile_cache
 
 
 def load_data(args):
@@ -48,6 +49,7 @@ def main():
     ap.add_argument("--cifar-npz", default=None)
     ap.add_argument("--out", default="runs/resnet_compare.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     train, val, classes, hw = load_data(args)
     out = {}

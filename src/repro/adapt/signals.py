@@ -193,14 +193,14 @@ def gns_from_accumulators(div_state: Any, estimator: str = "moment") -> jax.Arra
 
 
 @functools.lru_cache(maxsize=None)
-def _read_jit(estimator: str, reset: bool):
+def _read_jit(estimator: str):
     # deferred import: repro.core's __init__ pulls the controller shim, which
     # reaches back into repro.adapt — module-level would be a cycle
     from repro.core import diversity
 
     def read(div_state):
         est = diversity.estimate(div_state, estimator)
-        scalars = jnp.stack(
+        return jnp.stack(
             [
                 est,
                 gns_from_accumulators(div_state, estimator),
@@ -209,11 +209,6 @@ def _read_jit(estimator: str, reset: bool):
                 div_state.sample_count * est,
             ]
         )
-        if not reset:
-            # tick reads leave the accumulators untouched — returning them
-            # through the jit would copy the param-sized grad_sum tree
-            return scalars
-        return scalars, diversity.reset_state(div_state)
 
     return jax.jit(read)
 
@@ -236,11 +231,12 @@ def read_signals(
     running window).  Exactly ONE device->host transfer regardless of how
     many scalars are read (they come back stacked).
     """
+    from repro.core import diversity  # deferred: see _read_jit
+
+    scalars = _read_jit(estimator)(state.div_state)
     if reset:
-        scalars, div_state = _read_jit(estimator, True)(state.div_state)
-        state = state._replace(div_state=div_state)
-    else:
-        scalars = _read_jit(estimator, False)(state.div_state)
+        # eager, so the zeroed accumulators keep the state's sharding
+        state = state._replace(div_state=diversity.reset_state(state.div_state))
     vals = np.asarray(scalars)  # the single host transfer
     sig = Signals(
         diversity=float(vals[0]),

@@ -64,12 +64,10 @@ def init_state(params: PyTree, accum_dtype=jnp.float32) -> DiversityState:
 
 
 def reset_state(state: DiversityState) -> DiversityState:
-    return DiversityState(
-        grad_sum=ptu.tree_zeros_like(state.grad_sum),
-        sq_norm_sum=jnp.zeros((), jnp.float32),
-        mb_count=jnp.zeros((), jnp.float32),
-        sample_count=jnp.zeros((), jnp.float32),
-    )
+    """Zeroed accumulators.  Called eagerly, each leaf keeps the sharding of
+    the one it replaces (``zeros_like`` of a concrete array does; under jit
+    the compiler would be free to put the zeros anywhere)."""
+    return jax.tree.map(jnp.zeros_like, state)
 
 
 # ---------------------------------------------------------------------------

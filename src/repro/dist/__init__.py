@@ -12,9 +12,9 @@
 from repro.dist import compression, plan, sharding
 from repro.dist.plan import (
     ShardingPlan,
-    abstract_mesh,
     constrain,
     current_plan,
+    make_mesh,
     use_plan,
 )
 from repro.dist.sharding import (
@@ -26,9 +26,9 @@ from repro.dist.sharding import (
 
 __all__ = [
     "ShardingPlan",
-    "abstract_mesh",
     "constrain",
     "current_plan",
+    "make_mesh",
     "use_plan",
     "infer_pspecs",
     "batch_pspecs",

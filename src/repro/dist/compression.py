@@ -31,10 +31,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-try:  # moved out of experimental in newer jax
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 PyTree = Any
@@ -113,10 +109,10 @@ def make_compressed_pod_mean(mesh, axis_name: str):
         )
         return red, jax.tree.map(lambda e: e[None], new_err)
 
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
         out_specs=(P(), P(axis_name)),
-        check_rep=False,
+        check_vma=False,
     )

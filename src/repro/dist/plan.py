@@ -12,10 +12,11 @@ codebase uses:
 Model code never builds shardings directly.  The launch layer activates a
 plan (plus a table of named activation PartitionSpecs) with ``use_plan``;
 inside that context :func:`constrain` attaches ``with_sharding_constraint``
-to the named activations.  Outside any plan — CPU smoke tests, benchmarks,
-single-host runs — ``constrain`` is an EXACT no-op (returns its argument
-unchanged, inserts nothing into the jaxpr), which is what lets the same
-model code run everywhere.
+to the named activations, and :func:`shard_local` runs each Pallas kernel
+once per shard (the compiler cannot partition a Mosaic kernel).  Outside
+any plan — CPU smoke tests, benchmarks, single-host runs — ``constrain`` is
+an EXACT no-op (returns its argument unchanged, inserts nothing into the
+jaxpr), which is what lets the same model code run everywhere.
 
 The active plan lives in a ``contextvars.ContextVar`` so nesting and
 re-entrancy behave like lexical scoping, including across exceptions.
@@ -27,28 +28,28 @@ import contextlib
 import contextvars
 import dataclasses
 import math
-from typing import Any, Iterator, Mapping
+from typing import Any, Callable, Iterator, Mapping
 
 import jax
-from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 PyTree = Any
 
 AxisNames = Any  # str | tuple[str, ...]
 
 
-def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """Version-portable ``AbstractMesh`` constructor.
+def make_mesh(axis_shapes: tuple[int, ...], axis_names: tuple[str, ...], *,
+              devices=None) -> Mesh:
+    """The repo's one mesh constructor: ``jax.make_mesh`` with Auto axes.
 
-    jax <= 0.4.x takes a tuple of ``(name, size)`` pairs; newer releases take
-    ``(axis_sizes, axis_names)``.  Tests and the dry-run build fake
-    production-shape meshes through this so divisibility rules can be checked
-    without 512 devices.
+    ``jax.make_mesh`` defaults to Explicit axes, on which
+    ``with_sharding_constraint`` (what :func:`constrain` attaches) is an
+    error; every plan here relies on GSPMD propagation, i.e. Auto axes.
     """
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(AxisType.Auto,) * len(axis_names), devices=devices,
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,3 +161,36 @@ def constrain(x: jax.Array, name: str) -> jax.Array:
     if fitted is None:
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(plan.mesh, fitted))
+
+
+def shard_local(fn: Callable, args: tuple, in_roles: tuple, out_roles: tuple):
+    """``fn(*args)``, run once per shard of the active plan's mesh.
+
+    The compiler cannot partition a Pallas TPU kernel ("Mosaic kernels cannot
+    be automatically partitioned"), so under a plan the model calls each
+    kernel through ``jax.shard_map``.  ``in_roles`` gives, per argument, the
+    plan role of each dim: "dp" (the batch), "tp" (heads) or None (kept
+    whole); an argument whose roles are None is whole on every shard.
+    ``out_roles`` does the same for the single output.  A role whose axes do
+    not divide every dim it labels is dropped, and those dims stay whole:
+    still correct, at the cost of every shard computing them.  Outside a
+    plan, or on a one-device mesh, this is the plain call.
+    """
+    plan = current_plan()
+    if plan is None or plan.mesh.size == 1:
+        return fn(*args)
+    axes = {"dp": tuple(plan.dp) or None, "tp": plan.tp}
+    ok = {role: axes[role] is not None for role in axes}
+    for arg, roles in zip(args, in_roles):
+        for dim, role in zip(arg.shape, roles or ()):
+            if role is not None and dim % plan.axis_size(axes[role]):
+                ok[role] = False
+
+    def spec(roles) -> P:
+        return P(*(axes[r] if r is not None and ok[r] else None
+                   for r in (roles or ())))
+
+    return jax.shard_map(
+        fn, mesh=plan.mesh, in_specs=tuple(spec(r) for r in in_roles),
+        out_specs=spec(out_roles), check_vma=False,
+    )(*args)

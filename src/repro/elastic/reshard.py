@@ -74,13 +74,6 @@ def state_shardings(tree: PyTree, plan: ShardingPlan) -> PyTree:
     return shardings_of(infer_pspecs(tree, plan), plan)
 
 
-def _device_put(tree: PyTree, shardings, donate: bool) -> PyTree:
-    try:
-        return jax.device_put(tree, shardings, donate=donate)
-    except TypeError:  # jax without the donate kwarg: plain transfer
-        return jax.device_put(tree, shardings)
-
-
 def reshard(
     state: PyTree,
     src_plan: ShardingPlan | None,
@@ -99,8 +92,8 @@ def reshard(
     if same_plan(src_plan, dst_plan):
         return state
     if dst_plan is None:
-        return _device_put(state, jax.devices()[0], donate)
-    return _device_put(state, state_shardings(state, dst_plan), donate)
+        return jax.device_put(state, jax.devices()[0], donate=donate)
+    return jax.device_put(state, state_shardings(state, dst_plan), donate=donate)
 
 
 def place(tree: PyTree, plan: ShardingPlan | None) -> PyTree:

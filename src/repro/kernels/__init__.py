@@ -15,9 +15,10 @@ Modules
                 compression.
   ops.py        jit wrappers + dispatch: ``choose_method`` picks the psgn
                 factorisation by FLOP count, ``persample_sq_norm_tree``
-                groups same-shape layers into the fused kernel, and
-                ``default_interpret`` selects compiled Pallas on TPU /
-                interpret mode everywhere else (the one platform switch).
+                groups same-shape layers into the fused kernel.
+  (package)     ``default_interpret``, the one platform switch, and
+                ``resolve_interpret``, through which every kernel
+                resolves ``interpret=None``.
   ref.py        pure-jnp oracles — the property tests in
                 tests/test_kernels.py validate every kernel against these
                 in interpret mode; TPU is the execution target.
@@ -32,6 +33,23 @@ Dispatch into the lane
   through ``ops.persample_sq_norm_tree``; the gram tier always lands here.
 """
 
-from repro.kernels import attention, ops, psgn, quant, ref
+import jax
 
-__all__ = ["attention", "ops", "psgn", "quant", "ref"]
+
+def default_interpret() -> bool:
+    """The one platform switch of the lane: True (interpret mode)
+    everywhere except a real TPU backend."""
+    return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel's ``interpret=`` argument: None defers to
+    :func:`default_interpret`, an explicit bool forces that mode."""
+    return default_interpret() if interpret is None else bool(interpret)
+
+
+# submodules import the two functions above from this package
+from repro.kernels import attention, ops, psgn, quant, ref  # noqa: E402
+
+__all__ = ["attention", "default_interpret", "ops", "psgn", "quant", "ref",
+           "resolve_interpret"]

@@ -1,7 +1,7 @@
 """Pallas TPU attention kernels: flash prefill + fused paged decode.
 
 Three kernels behind ``cfg.attn_impl = "pallas"`` (models/transformer.py
-dispatches; ``kernels/ops.default_interpret`` decides interpret mode):
+dispatches; ``repro.kernels.default_interpret`` decides interpret mode):
 
   flash_attention   causal + sliding-window + softcap streaming-softmax
                     attention, matching ``models/attention.py::
@@ -32,7 +32,12 @@ dispatches; ``kernels/ops.default_interpret`` decides interpret mode):
 
 All kernels pad ragged shapes to block multiples internally (padding is
 masked, outputs sliced); GQA is handled by mapping head h onto KV head
-h // n_rep in the index_map.  ``kernels/ref.py`` holds the pure-jnp oracles
+h // n_rep in the index_map.  The flash kernels run head-major: q/k/v are
+transposed to ``(B, H, S, hd)`` so every block is a ``(block, hd)`` tile
+with the batch and head dimensions squeezed, which is what the TPU lowering
+asks of a block's last two dimensions; query positions travel as a
+``(S, 1)`` column and key positions/validity as a ``(1, S)`` row, so the
+mask broadcasts without a relayout.  ``kernels/ref.py`` holds the pure-jnp oracles
 the property tests (tests/test_kernels.py) validate against in interpret
 mode; TPU is the execution target.
 """
@@ -45,15 +50,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ops import default_interpret
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces; interpret mode accepts pltpu specs on CPU too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30  # matches models/attention.py
 
@@ -68,15 +67,12 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
     return jnp.pad(x, pads, constant_values=value)
 
 
-def _resolve(interpret):
-    return default_interpret() if interpret is None else bool(interpret)
-
-
 def _block_bias(q_pos, k_pos, q_valid, k_valid, causal, window):
     """(bq, bk) additive f32 bias — models/attention.py::_mask_bias plus
-    explicit row/key validity (the padding / gathered-garbage mask)."""
-    rel = q_pos[:, None] - k_pos[None, :]
-    ok = q_valid[:, None] & k_valid[None, :]
+    explicit row/key validity (the padding / gathered-garbage mask).
+    Query operands are (bq, 1) columns, key operands (1, bk) rows."""
+    rel = q_pos - k_pos
+    ok = q_valid & k_valid
     if causal:
         ok = ok & (rel >= 0)
     if window is not None:
@@ -100,21 +96,21 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, kval_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]  # (bq, hd)
-    k = k_ref[0, :, 0, :]  # (bk, hd)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]  # (bq, hd)
+    k = k_ref[...]  # (bk, hd)
+    v = v_ref[...]
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     if softcap is not None:
         logits = jnp.tanh(logits / softcap) * softcap
-    q_pos = qp_ref[0]  # (bq,) int32 absolute positions
-    k_pos = kp_ref[0]  # (bk,)
+    q_pos = qp_ref[...]  # (bq, 1) int32 absolute positions
+    k_pos = kp_ref[...]  # (1, bk)
     # positions are ABSOLUTE (chunk mode: unrelated to array indices), so
     # padding validity comes only from the sentinels: padded q rows carry a
     # negative position, padded/garbage keys carry k_valid = 0
     bias, _ = _block_bias(
-        q_pos, k_pos, q_pos >= 0, kval_ref[0] > 0, causal, window,
+        q_pos, k_pos, q_pos >= 0, kval_ref[...] > 0, causal, window,
     )
     logits = logits + bias
     m_prev, l_prev = m_ref[...], l_ref[...]
@@ -131,60 +127,91 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, kval_ref,
     @pl.when(kj == n_k - 1)
     def _finish():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = (m_ref[...] + jnp.log(l_safe))[:, 0]
+        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse_ref[...] = m_ref[...] + jnp.log(l_safe)
+
+
+def _head_major(x: jax.Array, block: int) -> jax.Array:
+    """(B, S, H, hd) -> (B, H, S_padded, hd)."""
+    return _pad_to(jnp.transpose(x, (0, 2, 1, 3)), 2, block)
+
+
+def _pick(grid_axis, i, j):
+    return i if grid_axis == 2 else j
+
+
+def _tile(block: int, hd: int, grid_axis: int, n_rep: int = 1):
+    """One (block, hd) tile of a head-major (B, H, S, hd) array whose S-block
+    index is grid axis ``grid_axis`` (2 or 3 of the (B, H, ., .) grid);
+    head h reads head h // n_rep (GQA)."""
+    return pl.BlockSpec(
+        (pl.Squeezed(), pl.Squeezed(), block, hd),
+        lambda b_, h_, i, j: (b_, h_ // n_rep, _pick(grid_axis, i, j), 0),
+    )
+
+
+def _stat(block: int, grid_axis: int):
+    """One (block, 1) column of a per-row statistic (B, H, S, 1)."""
+    return pl.BlockSpec(
+        (pl.Squeezed(), pl.Squeezed(), block, 1),
+        lambda b_, h_, i, j: (b_, h_, _pick(grid_axis, i, j), 0),
+    )
+
+
+def _col(block: int, grid_axis: int):
+    """One (block, 1) slice of an (S, 1) column (query positions)."""
+    return pl.BlockSpec((block, 1), lambda b_, h_, i, j: (_pick(grid_axis, i, j), 0))
+
+
+def _row(block: int, grid_axis: int):
+    """One (1, block) slice of a (1, S) row (key positions / validity)."""
+    return pl.BlockSpec((1, block), lambda b_, h_, i, j: (0, _pick(grid_axis, i, j)))
 
 
 def _flash_forward(q, k, v, q_pos, k_pos, k_valid, causal, window, softcap,
                    q_block, kv_block, interpret):
     """Shared streaming forward. Positions/validity are host arrays sized to
-    the PADDED seq lens; returns (out (B,Sq,H,hd), lse (B,H,Sqp))."""
+    the unpadded seq lens; returns (out (B,Sq,H,hd), lse (B,H,Sqp,1))."""
     b, sq, h, hd = q.shape
-    sk, kv = k.shape[1], k.shape[2]
+    kv = k.shape[2]
     n_rep = h // kv
-    qp = _pad_to(q, 1, q_block)
-    kp = _pad_to(k, 1, kv_block)
-    vp = _pad_to(v, 1, kv_block)
-    sqp, skp = qp.shape[1], kp.shape[1]
+    qt = _head_major(q, q_block)
+    kt = _head_major(k, kv_block)
+    vt = _head_major(v, kv_block)
+    sqp, skp = qt.shape[2], kt.shape[2]
     n_q, n_k = sqp // q_block, skp // kv_block
     q_pos = _pad_to(q_pos.astype(jnp.int32), 0, q_block, value=-(2 ** 30))
     k_pos = _pad_to(k_pos.astype(jnp.int32), 0, kv_block)
     k_valid = _pad_to(k_valid.astype(jnp.int32), 0, kv_block)
 
-    grid = (b, h, n_q, n_k)
     kernel = functools.partial(
         _flash_fwd_kernel, n_k=n_k, scale=hd ** -0.5, causal=causal,
         window=window, softcap=softcap,
     )
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, h, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, q_block, 1, hd), lambda b_, h_, qi, kj: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda b_, h_, qi, kj: (b_, kj, h_ // n_rep, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda b_, h_, qi, kj: (b_, kj, h_ // n_rep, 0)),
-            pl.BlockSpec((1, q_block), lambda b_, h_, qi, kj: (0, qi)),
-            pl.BlockSpec((1, kv_block), lambda b_, h_, qi, kj: (0, kj)),
-            pl.BlockSpec((1, kv_block), lambda b_, h_, qi, kj: (0, kj)),
+            _tile(q_block, hd, 2),
+            _tile(kv_block, hd, 3, n_rep),
+            _tile(kv_block, hd, 3, n_rep),
+            _col(q_block, 2),
+            _row(kv_block, 3),
+            _row(kv_block, 3),
         ],
-        out_specs=[
-            pl.BlockSpec((1, q_block, 1, hd), lambda b_, h_, qi, kj: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, 1, q_block), lambda b_, h_, qi, kj: (b_, h_, qi)),
-        ],
+        out_specs=[_tile(q_block, hd, 2), _stat(q_block, 2)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sqp, h, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sqp), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sqp, hd), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sqp, 1), jnp.float32),
         ],
         scratch_shapes=[
-            _VMEM((q_block, 1), jnp.float32),
-            _VMEM((q_block, 1), jnp.float32),
-            _VMEM((q_block, hd), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
+            pltpu.VMEM((q_block, 1), jnp.float32),
+            pltpu.VMEM((q_block, hd), jnp.float32),
         ],
-        interpret=_resolve(interpret),
-    )(qp, kp, vp, q_pos[None], k_pos[None], k_valid[None])
-    return out[:, :sq], lse
+        interpret=resolve_interpret(interpret),
+    )(qt, kt, vt, q_pos[:, None], k_pos[None], k_valid[None])
+    return jnp.transpose(out, (0, 2, 1, 3))[:, :sq], lse
 
 
 # ---------------------------------------------------------------------------
@@ -194,8 +221,9 @@ def _flash_forward(q, k, v, q_pos, k_pos, k_valid, causal, window, softcap,
 
 def _recompute_dlogits(q, k, v, do, lse, delta, q_pos, k_pos, q_valid, k_valid,
                        scale, causal, window, softcap):
-    """(p, dlogits) for one (bq, bk) tile.  Padded rows/keys force p = 0
-    explicitly: a padded q row's lse is garbage and exp() would overflow."""
+    """(p, dlogits) for one (bq, bk) tile; ``lse``/``delta`` are (bq, 1)
+    columns.  Padded rows/keys force p = 0 explicitly: a padded q row's lse
+    is garbage and exp() would overflow."""
     raw = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
@@ -205,11 +233,11 @@ def _recompute_dlogits(q, k, v, do, lse, delta, q_pos, k_pos, q_valid, k_valid,
     else:
         logits = raw
     _, ok = _block_bias(q_pos, k_pos, q_valid, k_valid, causal, window)
-    p = jnp.where(ok, jnp.exp(logits - lse[:, None]), 0.0)
+    p = jnp.where(ok, jnp.exp(logits - lse), 0.0)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    dlogits = p * (dp - delta[:, None])
+    dlogits = p * (dp - delta)
     if softcap is not None:
         dlogits = dlogits * (1.0 - capped * capped)
     return p, dlogits
@@ -224,12 +252,11 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pos, k_pos = qp_ref[0], kp_ref[0]
-    k = k_ref[0, :, 0, :]
+    q_pos, k_pos = qp_ref[...], kp_ref[...]
+    k = k_ref[...]
     _, dlogits = _recompute_dlogits(
-        q_ref[0, :, 0, :], k, v_ref[0, :, 0, :], do_ref[0, :, 0, :],
-        lse_ref[0, 0], dl_ref[0, 0], q_pos, k_pos,
-        q_pos >= 0, kval_ref[0] > 0,
+        q_ref[...], k, v_ref[...], do_ref[...], lse_ref[...], dl_ref[...],
+        q_pos, k_pos, q_pos >= 0, kval_ref[...] > 0,
         scale, causal, window, softcap,
     )
     acc_ref[...] += jax.lax.dot_general(
@@ -239,7 +266,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     @pl.when(kj == n_k - 1)
     def _finish():
-        dq_ref[0, :, 0, :] = acc_ref[...]
+        dq_ref[...] = acc_ref[...]
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
@@ -253,13 +280,12 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_pos, k_pos = qp_ref[0], kp_ref[0]
-    q = q_ref[0, :, 0, :]
-    do = do_ref[0, :, 0, :]
+    q_pos, k_pos = qp_ref[...], kp_ref[...]
+    q = q_ref[...]
+    do = do_ref[...]
     p, dlogits = _recompute_dlogits(
-        q, k_ref[0, :, 0, :], v_ref[0, :, 0, :], do,
-        lse_ref[0, 0], dl_ref[0, 0], q_pos, k_pos,
-        q_pos >= 0, kval_ref[0] > 0,
+        q, k_ref[...], v_ref[...], do, lse_ref[...], dl_ref[...],
+        q_pos, k_pos, q_pos >= 0, kval_ref[...] > 0,
         scale, causal, window, softcap,
     )
     dv_acc[...] += jax.lax.dot_general(
@@ -273,8 +299,8 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
 
     @pl.when(qi == n_q - 1)
     def _finish():
-        dk_ref[0, :, 0, :] = dk_acc[...]
-        dv_ref[0, :, 0, :] = dv_acc[...]
+        dk_ref[...] = dk_acc[...]
+        dv_ref[...] = dv_acc[...]
 
 
 def _flash_backward(q, k, v, out, lse, dout, causal, window, softcap,
@@ -283,28 +309,24 @@ def _flash_backward(q, k, v, out, lse, dout, causal, window, softcap,
     sk, kv_heads = k.shape[1], k.shape[2]
     n_rep = h // kv_heads
     scale = hd ** -0.5
-    interpret = _resolve(interpret)
+    interpret = resolve_interpret(interpret)
 
-    qp = _pad_to(q, 1, q_block)
-    dop = _pad_to(dout, 1, q_block)
-    kp = _pad_to(k, 1, kv_block)
-    vp = _pad_to(v, 1, kv_block)
-    sqp, skp = qp.shape[1], kp.shape[1]
+    qt = _head_major(q, q_block)
+    dot = _head_major(dout, q_block)
+    kt = _head_major(k, kv_block)
+    vt = _head_major(v, kv_block)
+    sqp, skp = qt.shape[2], kt.shape[2]
     n_q, n_k = sqp // q_block, skp // kv_block
-    # delta_i = rowsum(dout_i * out_i): (b, h, sqp)
+    # delta_i = rowsum(dout_i * out_i): (b, h, sqp, 1), like lse
     delta = _pad_to(
         jnp.einsum("bqhd,bqhd->bhq", dout.astype(jnp.float32),
-                   out.astype(jnp.float32)), 2, q_block,
+                   out.astype(jnp.float32))[..., None], 2, q_block,
     )
     q_pos = _pad_to(jnp.arange(sq, dtype=jnp.int32), 0, q_block,
-                    value=-(2 ** 30))[None]
+                    value=-(2 ** 30))[:, None]
     k_pos = _pad_to(jnp.arange(sk, dtype=jnp.int32), 0, kv_block)[None]
     k_valid = (k_pos < sk).astype(jnp.int32)
-
-    qspec = pl.BlockSpec((1, q_block, 1, hd), lambda b_, h_, i, j: (b_, i, h_, 0))
-    kspec = pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda b_, h_, i, j: (b_, i, h_ // n_rep, 0))
-    args = (qp, kp, vp, dop, lse, delta, q_pos, k_pos, k_valid)
+    args = (qt, kt, vt, dot, lse, delta, q_pos, k_pos, k_valid)
 
     # pass 1: dq — grid (B, H, nQ, nK), KV innermost
     dq = pl.pallas_call(
@@ -312,22 +334,19 @@ def _flash_backward(q, k, v, out, lse, dout, causal, window, softcap,
                           window=window, softcap=softcap),
         grid=(b, h, n_q, n_k),
         in_specs=[
-            qspec,
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda b_, h_, qi, kj: (b_, kj, h_ // n_rep, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda b_, h_, qi, kj: (b_, kj, h_ // n_rep, 0)),
-            qspec,
-            pl.BlockSpec((1, 1, q_block), lambda b_, h_, qi, kj: (b_, h_, qi)),
-            pl.BlockSpec((1, 1, q_block), lambda b_, h_, qi, kj: (b_, h_, qi)),
-            pl.BlockSpec((1, q_block), lambda b_, h_, qi, kj: (0, qi)),
-            pl.BlockSpec((1, kv_block), lambda b_, h_, qi, kj: (0, kj)),
-            pl.BlockSpec((1, kv_block), lambda b_, h_, qi, kj: (0, kj)),
+            _tile(q_block, hd, 2),
+            _tile(kv_block, hd, 3, n_rep),
+            _tile(kv_block, hd, 3, n_rep),
+            _tile(q_block, hd, 2),
+            _stat(q_block, 2),
+            _stat(q_block, 2),
+            _col(q_block, 2),
+            _row(kv_block, 3),
+            _row(kv_block, 3),
         ],
-        out_specs=pl.BlockSpec((1, q_block, 1, hd),
-                               lambda b_, h_, qi, kj: (b_, qi, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sqp, h, hd), jnp.float32),
-        scratch_shapes=[_VMEM((q_block, hd), jnp.float32)],
+        out_specs=_tile(q_block, hd, 2),
+        out_shape=jax.ShapeDtypeStruct((b, h, sqp, hd), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((q_block, hd), jnp.float32)],
         interpret=interpret,
     )(*args)
 
@@ -338,35 +357,32 @@ def _flash_backward(q, k, v, out, lse, dout, causal, window, softcap,
                           window=window, softcap=softcap),
         grid=(b, h, n_k, n_q),
         in_specs=[
-            pl.BlockSpec((1, q_block, 1, hd), lambda b_, h_, kj, qi: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda b_, h_, kj, qi: (b_, kj, h_ // n_rep, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd),
-                         lambda b_, h_, kj, qi: (b_, kj, h_ // n_rep, 0)),
-            pl.BlockSpec((1, q_block, 1, hd), lambda b_, h_, kj, qi: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, 1, q_block), lambda b_, h_, kj, qi: (b_, h_, qi)),
-            pl.BlockSpec((1, 1, q_block), lambda b_, h_, kj, qi: (b_, h_, qi)),
-            pl.BlockSpec((1, q_block), lambda b_, h_, kj, qi: (0, qi)),
-            pl.BlockSpec((1, kv_block), lambda b_, h_, kj, qi: (0, kj)),
-            pl.BlockSpec((1, kv_block), lambda b_, h_, kj, qi: (0, kj)),
+            _tile(q_block, hd, 3),
+            _tile(kv_block, hd, 2, n_rep),
+            _tile(kv_block, hd, 2, n_rep),
+            _tile(q_block, hd, 3),
+            _stat(q_block, 3),
+            _stat(q_block, 3),
+            _col(q_block, 3),
+            _row(kv_block, 2),
+            _row(kv_block, 2),
         ],
-        out_specs=[
-            pl.BlockSpec((1, kv_block, 1, hd), lambda b_, h_, kj, qi: (b_, kj, h_, 0)),
-            pl.BlockSpec((1, kv_block, 1, hd), lambda b_, h_, kj, qi: (b_, kj, h_, 0)),
-        ],
+        out_specs=[_tile(kv_block, hd, 2), _tile(kv_block, hd, 2)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, skp, h, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, skp, h, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, skp, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, skp, hd), jnp.float32),
         ],
-        scratch_shapes=[_VMEM((kv_block, hd), jnp.float32),
-                        _VMEM((kv_block, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((kv_block, hd), jnp.float32),
+                        pltpu.VMEM((kv_block, hd), jnp.float32)],
         interpret=interpret,
     )(*args)
 
-    dq = dq[:, :sq].astype(q.dtype)
-    dk = dk_rep[:, :sk].reshape(b, sk, kv_heads, n_rep, hd).sum(3).astype(k.dtype)
-    dv = dv_rep[:, :sk].reshape(b, sk, kv_heads, n_rep, hd).sum(3).astype(v.dtype)
-    return dq, dk, dv
+    def fold(g):  # (B, H, Skp, hd) -> (B, Sk, KV, hd), summing each group
+        g = g[:, :, :sk].reshape(b, kv_heads, n_rep, sk, hd).sum(2)
+        return jnp.transpose(g, (0, 2, 1, 3))
+
+    dq = jnp.transpose(dq[:, :, :sq], (0, 2, 1, 3)).astype(q.dtype)
+    return dq, fold(dk_rep).astype(k.dtype), fold(dv_rep).astype(v.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -543,5 +559,5 @@ def paged_decode_attention(
                           softcap=softcap),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, hd), q.dtype),
-        interpret=_resolve(interpret),
+        interpret=resolve_interpret(interpret),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k, pool_v)

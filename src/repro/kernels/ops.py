@@ -1,9 +1,11 @@
 """Jit-ready wrappers around the Pallas kernels with cost-model dispatch.
 
-``default_interpret`` is the one platform switch for the whole kernel lane:
-compiled Pallas on TPU, interpret mode (pure-jax emulation, still inside
-jit) everywhere else — tests exercise the real kernel bodies on CPU.
-Callers can force either mode by passing ``interpret=`` explicitly.
+``repro.kernels.default_interpret`` is the one platform switch for the
+whole kernel lane: compiled Pallas on TPU, interpret mode (pure-jax
+emulation, still inside jit) everywhere else — tests exercise the real
+kernel bodies on CPU.  Every kernel takes ``interpret=None`` by default and
+resolves it through that switch; callers can force either mode by passing
+``interpret=`` explicitly.
 """
 
 from __future__ import annotations
@@ -17,9 +19,8 @@ from repro.kernels import psgn as psgn_kernels
 from repro.kernels import quant as quant_kernels
 
 
-def default_interpret() -> bool:
-    """True (interpret mode) everywhere except a real TPU backend."""
-    return jax.default_backend() != "tpu"
+#: VMEM the gram kernel's streamed input blocks may take (of 16 MiB scoped)
+_GRAM_VMEM = 12 << 20
 
 
 def choose_method(s: int, d_in: int, d_out: int) -> str:
@@ -43,8 +44,6 @@ def persample_sq_norm(
     ||x_b delta_b^T||_F^2 = ||x_b||^2 * ||delta_b||^2 — no kernel needed.
     ``interpret=None`` resolves via ``default_interpret()``.
     """
-    if interpret is None:
-        interpret = default_interpret()
     if x.ndim == 2:
         xn = jnp.sum(jnp.square(x.astype(jnp.float32)), axis=-1)
         dn = jnp.sum(jnp.square(delta.astype(jnp.float32)), axis=-1)
@@ -62,7 +61,11 @@ def persample_sq_norm(
             interpret=interpret,
         )
     if method == "gram":
-        blk = min(256, _round_pow2(s))
+        # four (blk, features) input blocks, double-buffered, must fit the
+        # kernel's scoped VMEM: at S=2048, Din+Dout=15104 (a 7B FFN layer) a
+        # 256-row block needs 29.5 MiB of the 16 MiB a TPU kernel gets
+        per_row = 4 * (d_in + d_out) * jnp.dtype(x.dtype).itemsize
+        blk = min(256, _round_pow2(s), _round_pow2(max(_GRAM_VMEM // per_row, 8)))
         return psgn_kernels.psgn_gram(x, delta, block_si=blk, block_sj=blk,
                                       interpret=interpret)
     raise ValueError(f"unknown method {method!r}")
@@ -104,8 +107,6 @@ def persample_sq_norm_tree(
     (exact for bias-complete dense models; probes see the same delta the
     bias does).
     """
-    if interpret is None:
-        interpret = default_interpret()
     groups: dict[tuple, list[str]] = {}
     for name, x in acts.items():
         d = deltas[name]

@@ -25,9 +25,9 @@ target TPU for execution.
 
 FUSED (psgn_fused) stacks L same-shape dense layers into one launch — grid
 (B, L, Din/bi, Dout/bj, S/bs) — and accumulates the CROSS-LAYER sum straight
-into a (B, 1) output block that stays resident across all inner grid steps,
-so the exact diversity tier issues one kernel for a whole probe tree instead
-of L separate launches with an XLA reduction after each.
+into one (8, 128) output tile per sample that stays resident across all
+inner grid steps, so the exact diversity tier issues one kernel for a whole
+probe tree instead of L separate launches with an XLA reduction after each.
 """
 
 from __future__ import annotations
@@ -38,13 +38,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory spaces; interpret mode accepts pltpu.VMEM on CPU too
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels import resolve_interpret
+
+# Partial sums leave a kernel as one broadcast (8, 128) f32 tile per grid
+# step: the TPU lowering wants a block's last two dimensions aligned to that
+# tiling, which a (1, 1) scalar block is not.
+_TILE = (8, 128)
+
+
+def _scalar_tile(x: jax.Array) -> jax.Array:
+    """Full reduction of a 2-D block to a broadcast (8, 128) tile."""
+    total = jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1, keepdims=True)
+    return jnp.broadcast_to(total, _TILE)
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -78,7 +85,7 @@ def _direct_kernel(x_ref, d_ref, o_ref, acc_ref, *, n_s: int):
     @pl.when(s == n_s - 1)
     def _finish():
         blk = acc_ref[...]
-        o_ref[0, 0, 0] = jnp.sum(blk * blk)
+        o_ref[...] = _scalar_tile(blk * blk)
 
 
 def psgn_direct(
@@ -88,7 +95,7 @@ def psgn_direct(
     block_i: int = 128,
     block_j: int = 128,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """(B,) per-sample ||X_b^T Delta_b||_F^2 (f32)."""
     assert x.ndim == 3 and delta.ndim == 3 and x.shape[:2] == delta.shape[:2]
@@ -100,12 +107,6 @@ def psgn_direct(
     n_i, n_j, n_s = din // block_i, dout // block_j, s // block_s
 
     grid = (b, n_i, n_j, n_s)
-    out_shape = jax.ShapeDtypeStruct((b, n_i, n_j), jnp.float32)
-    scratch = (
-        [pltpu.VMEM((block_i, block_j), jnp.float32)]
-        if _VMEM is not None
-        else [pl.BlockSpec(memory_space=None)]  # pragma: no cover
-    )
     partials = pl.pallas_call(
         functools.partial(_direct_kernel, n_s=n_s),
         grid=grid,
@@ -113,12 +114,13 @@ def psgn_direct(
             pl.BlockSpec((1, block_s, block_i), lambda bb, i, j, ss: (bb, ss, i)),
             pl.BlockSpec((1, block_s, block_j), lambda bb, i, j, ss: (bb, ss, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1), lambda bb, i, j, ss: (bb, i, j)),
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
+        out_specs=pl.BlockSpec((pl.Squeezed(),) * 3 + _TILE,
+                               lambda bb, i, j, ss: (bb, i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, n_i, n_j) + _TILE, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_i, block_j), jnp.float32)],
+        interpret=resolve_interpret(interpret),
     )(x, delta)
-    return partials.sum(axis=(1, 2))
+    return partials[..., 0, 0].sum(axis=(1, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -137,7 +139,7 @@ def _gram_kernel(xi_ref, xj_ref, di_ref, dj_ref, o_ref):
     gd = jax.lax.dot_general(
         di, dj, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    o_ref[0, 0, 0] = jnp.sum(gx * gd)
+    o_ref[...] = _scalar_tile(gx * gd)
 
 
 def psgn_gram(
@@ -146,7 +148,7 @@ def psgn_gram(
     *,
     block_si: int = 256,
     block_sj: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """(B,) per-sample sum_{t,t'} (x_t.x_t')(d_t.d_t') == ||X^T D||_F^2."""
     assert x.ndim == 3 and delta.ndim == 3 and x.shape[:2] == delta.shape[:2]
@@ -157,7 +159,6 @@ def psgn_gram(
     n_i, n_j = s // block_si, s // block_sj
 
     grid = (b, n_i, n_j)
-    out_shape = jax.ShapeDtypeStruct((b, n_i, n_j), jnp.float32)
     partials = pl.pallas_call(
         _gram_kernel,
         grid=grid,
@@ -167,11 +168,12 @@ def psgn_gram(
             pl.BlockSpec((1, block_si, delta.shape[2]), lambda bb, i, j: (bb, i, 0)),
             pl.BlockSpec((1, block_sj, delta.shape[2]), lambda bb, i, j: (bb, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1), lambda bb, i, j: (bb, i, j)),
-        out_shape=out_shape,
-        interpret=interpret,
+        out_specs=pl.BlockSpec((pl.Squeezed(),) * 3 + _TILE,
+                               lambda bb, i, j: (bb, i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, n_i, n_j) + _TILE, jnp.float32),
+        interpret=resolve_interpret(interpret),
     )(x, x, delta, delta)
-    return partials.sum(axis=(1, 2))
+    return partials[..., 0, 0].sum(axis=(1, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +205,7 @@ def _fused_kernel(x_ref, d_ref, o_ref, acc_ref, *, n_s: int):
     @pl.when(s == n_s - 1)
     def _finish():
         blk = acc_ref[...]
-        o_ref[0, 0] += jnp.sum(blk * blk)
+        o_ref[...] += _scalar_tile(blk * blk)
 
 
 def psgn_fused(
@@ -213,11 +215,11 @@ def psgn_fused(
     block_i: int = 128,
     block_j: int = 128,
     block_s: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """(B,) sum over the L layers of per-sample ||X^T D||_F^2, one launch.
 
-    The (B, 1) output block is revisited by every (l, i, j, s) step for a
+    Sample b's (8, 128) output tile is revisited by every (l, i, j, s) step for a
     fixed b (the batch axis is outermost), so the cross-layer + cross-tile
     reduction happens in VMEM instead of as L separate XLA reductions.
     """
@@ -230,11 +232,6 @@ def psgn_fused(
     n_i, n_j, n_s = din // block_i, dout // block_j, s // block_s
 
     grid = (b, n_l, n_i, n_j, n_s)
-    scratch = (
-        [pltpu.VMEM((block_i, block_j), jnp.float32)]
-        if _VMEM is not None
-        else [pl.BlockSpec(memory_space=None)]  # pragma: no cover
-    )
     out = pl.pallas_call(
         functools.partial(_fused_kernel, n_s=n_s),
         grid=grid,
@@ -244,9 +241,10 @@ def psgn_fused(
             pl.BlockSpec((1, 1, block_s, block_j),
                          lambda bb, ll, i, j, ss: (ll, bb, ss, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda bb, ll, i, j, ss: (bb, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
-        scratch_shapes=scratch,
-        interpret=interpret,
+        out_specs=pl.BlockSpec((pl.Squeezed(),) + _TILE,
+                               lambda bb, ll, i, j, ss: (bb, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b,) + _TILE, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_i, block_j), jnp.float32)],
+        interpret=resolve_interpret(interpret),
     )(x, delta)
-    return out[:, 0]
+    return out[:, 0, 0]

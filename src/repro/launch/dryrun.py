@@ -274,8 +274,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str | None,
                 compiled = lowered.compile()
         mem = compiled.memory_analysis()
         raw_cost = compiled.cost_analysis()
-        if isinstance(raw_cost, (list, tuple)):  # jax<=0.4.x returns [dict]
-            raw_cost = raw_cost[0] if raw_cost else {}
         hlo_text = compiled.as_text()
         prog = hlo_lib.HloProgram(hlo_text)
         analysis = prog.analyze()  # trip-count-aware, per-device

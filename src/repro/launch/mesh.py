@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist.plan import ShardingPlan
+from repro.dist.plan import ShardingPlan, make_mesh
 
 GIB = 1 << 30
 
@@ -18,7 +18,7 @@ GIB = 1 << 30
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_plan(mesh, *, param_bytes: int | None = None,

@@ -30,11 +30,12 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.elastic import MeshLadder
 from repro.models import transformer as tf
 from repro.obs import from_cli as obs_from_cli
 from repro.serve import POLICIES, Request, ServeEngine
+from repro.utils.xla_env import enable_compile_cache
 
 
 def build_requests(cfg, n: int, *, max_new: int, seed: int,
@@ -126,6 +127,7 @@ def main():
                          "(repro.obs.runlog; read it with launch/monitor.py); "
                          "bare --runlog means <--trace DIR>/runlog.jsonl")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=not args.full_size)
     if not args.full_size:
@@ -143,7 +145,7 @@ def main():
             )
         ladder = MeshLadder(jax.devices()[:ndev], granule=1)
     elif args.dp:
-        mesh = jax.make_mesh((args.dp,), ("data",))
+        mesh = make_mesh((args.dp,), ("data",))
         plan_ctx = use_plan(ShardingPlan(mesh=mesh, tp=None))
 
     tracer, runlog = obs_from_cli(

@@ -15,6 +15,9 @@ from the restored batch size.
 
   python -m repro.launch.supervisor --epochs 6 --fail-at 3 --elastic
 
+A ladder needs several devices; on a CPU host ask for them with
+``JAX_PLATFORMS=cpu`` and ``--devices 8``.
+
 With ``--pods N`` the job runs on a ``repro.pod.PodLadder`` (cross-pod rungs
 move compressed gradients) and ``--lose-pod EPOCH[:POD]`` injects a HOST
 loss: instead of crash + checkpoint restore, the supervisor marks the pod
@@ -22,7 +25,8 @@ unhealthy and DEMOTES — the surviving state reshards onto the widest
 all-healthy rung and training carries straight on (typed ``pod_lost`` /
 ``demote`` run-log events record it).
 
-  python -m repro.launch.supervisor --epochs 6 --pods 2 --lose-pod 3
+  JAX_PLATFORMS=cpu python -m repro.launch.supervisor --devices 8 \
+      --epochs 6 --pods 2 --lose-pod 3
 """
 
 from __future__ import annotations
@@ -35,10 +39,10 @@ import numpy as np
 
 from repro.utils.logging import get_logger
 
-# NOTE: nothing at module level may *initialize* the jax backend: main()
-# forces the CPU host-device count via XLA_FLAGS, which must be set before
-# the first device use in the process (repro.ckpt is imported lazily in
-# run_supervised for the same reason).
+# NOTE: nothing at module level may *initialize* the jax backend: with
+# --devices, main() forces the CPU host-device count via XLA_FLAGS, which
+# must be set before the first device use in the process (repro.ckpt is
+# imported lazily in run_supervised for the same reason).
 
 log = get_logger("supervisor")
 
@@ -226,9 +230,8 @@ def main():
                          "widest all-healthy rung instead of restarting; "
                          "repeatable")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N CPU host devices (before first jax use; "
-                         "--elastic/--pods default to 8 so the ladder has "
-                         "rungs)")
+                    help="force N CPU host devices (before first jax use); "
+                         "pair with JAX_PLATFORMS=cpu for the CPU harness")
     ap.add_argument("--trace", default=None, metavar="DIR",
                     help="record a Chrome/Perfetto trace (repro.obs) spanning "
                          "every restart; writes DIR/trace.json at exit")
@@ -239,15 +242,16 @@ def main():
                          "DIR>/runlog.jsonl")
     args = ap.parse_args()
 
-    ndev = args.devices or (8 if (args.elastic or args.pods) else 0)
-    if ndev:
+    from repro.utils.xla_env import enable_compile_cache, force_host_device_count
+
+    if args.devices:
         # effective until the first backend init (first device use), which in
         # this process is the trainer build below
-        from repro.utils.xla_env import force_host_device_count
-
-        force_host_device_count(ndev)
+        force_host_device_count(args.devices)
 
     import jax
+
+    enable_compile_cache()
 
     from repro.adapt import (
         AdaBatchPolicy,

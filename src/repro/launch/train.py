@@ -35,12 +35,13 @@ from repro.adapt import (
 )
 from repro.core import step_decay
 from repro.data import imagelike_classification, sigmoid_synthetic
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.elastic import MeshLadder
 from repro.obs import from_cli as obs_from_cli
 from repro.optim import sgd
 from repro.train.loop import ModelFns, Trainer
 from repro.ckpt import CheckpointManager
+from repro.utils.xla_env import enable_compile_cache
 
 
 def build_task(task: str, seed: int):
@@ -169,6 +170,7 @@ def main():
                          "(repro.obs.runlog; read it with launch/monitor.py); "
                          "bare --runlog means <--trace DIR>/runlog.jsonl")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.method == "oracle":
         args.estimator = "oracle"
@@ -190,7 +192,7 @@ def main():
             )
         ladder = MeshLadder(jax.devices()[:ndev], granule=args.granule)
     elif args.dp:
-        mesh = jax.make_mesh((args.dp,), ("data",))
+        mesh = make_mesh((args.dp,), ("data",))
         plan_ctx = use_plan(ShardingPlan(mesh=mesh))
 
     tracer, runlog = obs_from_cli(

@@ -28,7 +28,6 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.layers import ACTIVATIONS
@@ -154,7 +153,7 @@ def moe_apply_ep(
         top_k=top_k, capacity_factor=capacity_factor, act=act,
         ep_axes=ep_axes, tp_axis=tp_axis, n_ep=n_ep, e_total=e_total,
     )
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -165,7 +164,7 @@ def moe_apply_ep(
             P(ep_axes, tp_axis, None),  # w_out (E, ff, d)
         ),
         out_specs=(P(dp, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return mapped(x, params["router"]["kernel"], params["w_gate"],
                   params["w_up"], params["w_out"])

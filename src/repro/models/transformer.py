@@ -13,6 +13,7 @@ at vocab 256k that tensor alone would be ~0.5 TB for the train_4k cell).
 Entry points:
   init_params(cfg, key)          parameter pytree (stacked blocks)
   loss_fn(cfg, params, batch)    scalar mean CE loss (+ MoE aux)
+  forward(cfg, params, batch)    (B,S,V) f32 logits of one full pass
   prefill_step(cfg, params, batch)  -> (last_logits, cache)
   decode_step(cfg, params, cache, tokens) -> (logits, cache)
   init_cache(cfg, batch, seq_len)   cache pytree (or ShapeDtypeStructs via
@@ -28,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.dist.plan import constrain
+from repro.dist.plan import constrain, shard_local
 from repro.kernels import attention as kernels_attn
 from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
@@ -50,6 +51,24 @@ PyTree = Any
 
 def _norm(cfg: ModelConfig, params, x):
     return rms_norm(params, x) if cfg.norm_type == "rms" else layer_norm(params, x)
+
+
+# Plan roles of each dim for the Pallas kernels (dist.plan.shard_local):
+# (B, S, heads, hd) activations split the batch over dp and heads over tp;
+# the paged KV pool (blocks, block, kv_heads, hd) splits only its heads,
+# since a decode lane may read any block.
+_HEADS = ("dp", None, "tp", None)
+_POOL = (None, None, "tp", None)
+
+
+def _flash_pallas(cfg: ModelConfig, q, k, v, causal: bool, window, s: int):
+    def fn(q, k, v):
+        return kernels_attn.flash_attention(
+            q, k, v, causal, window, cfg.attn_softcap,
+            min(cfg.flash_q_block, s), min(cfg.flash_kv_block, s),
+        )
+
+    return shard_local(fn, (q, k, v), (_HEADS,) * 3, _HEADS)
 
 
 def _cdtype(cfg: ModelConfig):
@@ -152,10 +171,7 @@ def _attn_sublayer(cfg: ModelConfig, p: dict, x: jax.Array, kind: str,
     window = cfg.window if kind == "attn_local" else None
     impl = attn_lib.resolve_impl(cfg, s)
     if impl == "pallas":
-        out = kernels_attn.flash_attention(
-            q, k, v, cfg.causal, window, cfg.attn_softcap,
-            min(cfg.flash_q_block, s), min(cfg.flash_kv_block, s),
-        )
+        out = _flash_pallas(cfg, q, k, v, cfg.causal, window, s)
     elif impl == "flash":
         out = attn_lib.flash_attention(
             q, k, v, cfg.causal, window, cfg.attn_softcap,
@@ -493,8 +509,11 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
                     # fused lane: the table gather happens inside the kernel's
                     # KV loop — the (B, n_max*block, KV, hd) gathered context
                     # below never materialises
-                    h = kernels_attn.paged_decode_attention(
-                        q, pk, pv, tables, posv + 1, softcap=cfg.attn_softcap,
+                    h = shard_local(
+                        functools.partial(kernels_attn.paged_decode_attention,
+                                          softcap=cfg.attn_softcap),
+                        (q, pk, pv, tables, posv + 1),
+                        (_HEADS, _POOL, _POOL, ("dp", None), ("dp",)), _HEADS,
                     )
                 else:
                     gk = jnp.take(pk, tables, axis=0).reshape(b, -1, cfg.num_kv_heads, hd)
@@ -556,6 +575,21 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
     return logits, new_caches, new_pages
 
 
+def forward(cfg: ModelConfig, params: PyTree, batch: dict,
+            moe_groups: int = 1) -> jax.Array:
+    """Logits (B, S, V) in f32 at every position of one full-sequence pass:
+    the encoder's output, and the reference that the incremental serving
+    path (prefill chunks, then decode steps) is checked against."""
+    x = _embed_input(cfg, params, batch)
+    positions = jnp.arange(x.shape[1])[None, :]
+    x, _ = _run_stack(cfg, params, x, positions, moe_groups)
+    x = _norm(cfg, params["final_norm"], x)
+    logits = (x @ params["lm_head"]["kernel"].astype(x.dtype)).astype(jnp.float32)
+    if cfg.final_softcap is not None:
+        logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
+    return logits
+
+
 def prefill_step(cfg: ModelConfig, params: PyTree, batch: dict,
                  moe_groups: int = 1) -> tuple[jax.Array, PyTree]:
     """Encode a prompt; returns (last-position logits, populated cache).
@@ -568,10 +602,7 @@ def prefill_step(cfg: ModelConfig, params: PyTree, batch: dict,
     hd = cfg.resolved_head_dim
 
     if not cfg.causal:  # encoder: plain forward
-        h, _ = _run_stack(cfg, params, x, positions, moe_groups=moe_groups)
-        h = _norm(cfg, params["final_norm"], h)
-        logits = (h @ params["lm_head"]["kernel"].astype(h.dtype)).astype(jnp.float32)
-        return logits, {}
+        return forward(cfg, params, batch, moe_groups), {}
 
     def layer_body(carry, layer):
         x = carry
@@ -603,10 +634,7 @@ def prefill_step(cfg: ModelConfig, params: PyTree, batch: dict,
                 # picks by length, a pinned impl is obeyed
                 impl = attn_lib.resolve_impl(cfg, s)
                 if impl == "pallas":
-                    h = kernels_attn.flash_attention(
-                        q, k, v, True, window, cfg.attn_softcap,
-                        min(cfg.flash_q_block, s), min(cfg.flash_kv_block, s),
-                    )
+                    h = _flash_pallas(cfg, q, k, v, True, window, s)
                 elif impl == "flash":
                     h = attn_lib.flash_attention(
                         q, k, v, True, window, cfg.attn_softcap,
@@ -682,12 +710,14 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
         # runs the tiled kernel (pads ragged K internally), everything else
         # keeps the XLA chunk_attention
         if cfg.attn_impl == "pallas":
-            return kernels_attn.chunk_attention(
-                q, k, v, q_pos, k_pos, k_valid, window=window,
+            fn = functools.partial(
+                kernels_attn.chunk_attention, window=window,
                 softcap=cfg.attn_softcap,
                 q_block=min(cfg.flash_q_block, c),
                 kv_block=min(cfg.flash_kv_block, k.shape[1]),
             )
+            return shard_local(fn, (q, k, v, q_pos, k_pos, k_valid),
+                               (_HEADS,) * 3 + (None,) * 3, _HEADS)
         return attn_lib.chunk_attention(
             q, k, v, q_pos, k_pos, k_valid, window=window,
             softcap=cfg.attn_softcap,

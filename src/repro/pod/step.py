@@ -27,10 +27,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-try:  # moved out of experimental in newer jax
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import diversity
@@ -149,10 +145,10 @@ def make_pod_train_step(
         params=P(), opt_state=P(), div_state=P(), step=P(),
         err_state=P(pod_axis),
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(state_spec, P((pod_axis, data_axis)), P()),
         out_specs=(state_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )

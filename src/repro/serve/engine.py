@@ -63,6 +63,7 @@ Pieces:
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 import warnings
@@ -75,7 +76,7 @@ import numpy as np
 from repro.adapt.signals import ThroughputWindow
 from repro.configs.base import ModelConfig
 from repro.core.batch_policy import bucket
-from repro.dist.plan import current_plan
+from repro.dist.plan import current_plan, use_plan
 from repro.dist.sharding import cache_pspecs, shardings_of
 from repro.elastic import MeshLadder, place
 from repro.models import transformer as tf
@@ -449,21 +450,28 @@ class ServeEngine:
             return self._exes[key]
         if kind == "decode":
             self.stats.bucket_misses += 1
-        fn = fn()
-        kwargs = {}
-        if donate and self.donate:
-            kwargs["donate_argnums"] = donate
-        if out_pin is not None and self._live_plan is not None:
-            pin = out_pin()
-            if pin is not None:
-                kwargs["out_shardings"] = pin
-        t0 = time.perf_counter()
-        with warnings.catch_warnings():
+        # On an elastic rung the ladder owns the plan: trace under it, the
+        # shape pass of ``out_pin`` included (JAX caches a trace without the
+        # plan in its key), so the model's plan-aware calls
+        # (dist.plan.shard_local around each Pallas kernel, which the
+        # compiler cannot partition) see the rung's mesh.
+        plan_ctx = (use_plan(self._rung.plan) if self._rung is not None
+                    else contextlib.nullcontext())
+        with plan_ctx, warnings.catch_warnings():
+            fn = fn()
+            kwargs = {}
+            if donate and self.donate:
+                kwargs["donate_argnums"] = donate
+            if out_pin is not None and self._live_plan is not None:
+                pin = out_pin()
+                if pin is not None:
+                    kwargs["out_shardings"] = pin
             # a grow-gather's donated (smaller) cache cannot alias the larger
             # output — partial donation is expected there, not a leak
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable"
             )
+            t0 = time.perf_counter()
             with self.tracer.span("compile", scope="serve", kind=kind,
                                   key=str(key)):
                 exe = jax.jit(fn, **kwargs).lower(*args).compile()

@@ -34,6 +34,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
 from repro.obs import metrics as metrics_lib
 from repro.obs import runlog as runlog_lib
@@ -243,6 +244,22 @@ class StepEngine:
             self._jits[jkey] = jax.jit(fn, **kwargs)
         return self._jits[jkey]
 
+    def _pinned(self, key: int, state: TrainState) -> Callable:
+        """``jitted(key)``, with the state output pinned to the layout of the
+        state passed in when that state lives on a mesh and the caller gave
+        no ``out_shardings``.  Left free, the compiler may lay an output out
+        differently (a replicated parameter comes back sharded like its
+        gradient), and the next step's executable, which is sharding-exact,
+        would refuse the state it is handed."""
+        fn = self.jitted(key)
+        if self._out_shardings is not None:
+            return fn
+        shardings = jax.tree.map(lambda x: x.sharding, state)
+        if not any(isinstance(sh, NamedSharding) for sh in jax.tree.leaves(shardings)):
+            return fn
+        return jax.jit(fn, out_shardings=(shardings, None),
+                       donate_argnums=(0,) if self.donate else ())
+
     def _executable(self, key: int, state: TrainState, batch: PyTree, lr):
         # AOT executables are shape- and sharding-exact, so the cache key
         # carries the full batch signature, the rung, and the estimator tier,
@@ -266,7 +283,7 @@ class StepEngine:
         # jit retrace behaviour.
         with self.tracer.span("compile", scope="train", bucket=key,
                               rung=self.rung, tier=str(self.tier)):
-            compiled = self.jitted(key).lower(state, batch, lr).compile()
+            compiled = self._pinned(key, state).lower(state, batch, lr).compile()
         dt = time.perf_counter() - t0
         if self.runlog.enabled:
             self.runlog.emit("compile", scope="train", what=f"bucket={key}",

@@ -108,7 +108,7 @@ def make_train_step(
     "auto" keeps vmap whenever ``example_loss`` is provided (bit-stable
     default) and falls back to the kernel path when only probes exist.
     ``psn_interpret`` forces the Pallas interpret flag (None = on-TPU
-    detection via kernels/ops.default_interpret).
+    detection via repro.kernels.default_interpret).
     """
     if loss_fn is None:
         if cfg is None:

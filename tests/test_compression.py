@@ -1,6 +1,7 @@
 """Gradient compression: quantisation error, error feedback, multi-device
 compressed reduction (8 host devices in a subprocess)."""
 
+import os
 import subprocess
 import sys
 
@@ -10,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.dist.compression import compress_leaf, init_error_state
+from repro.dist.plan import make_mesh
 
 
 def test_error_feedback_unbiased_over_time():
@@ -41,7 +43,7 @@ def test_make_compressed_pod_mean_keeps_per_pod_residuals():
     fold its own quantization error back, or error feedback is broken)."""
     from repro.dist.compression import make_compressed_pod_mean
 
-    mesh = jax.make_mesh((8,), ("pod",))  # conftest forces 8 host devices
+    mesh = make_mesh((8,), ("pod",))  # conftest forces 8 host devices
     r = np.random.default_rng(1)
     g = jnp.asarray(r.standard_normal((8, 4, 16)), jnp.float32)  # stacked
     grads, err = {"w": g}, init_error_state({"w": g})
@@ -64,10 +66,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.dist.compression import compressed_pod_mean, init_error_state
+from repro.dist.plan import make_mesh
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_mesh((8,), ("pod",))
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.standard_normal((8, 32)), jnp.float32)  # per-pod grads
 grads = {"w": g}
@@ -77,8 +79,8 @@ def f(g_shard, err):
     red, new_err = compressed_pod_mean({"w": g_shard[0]}, err, "pod")
     return red["w"], new_err
 
-fm = shard_map(f, mesh=mesh, in_specs=(P("pod"), P()), out_specs=(P(), P()),
-               check_rep=False)
+fm = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P()), out_specs=(P(), P()),
+                   check_vma=False)
 red, _ = jax.jit(fm)(grads["w"].reshape(8, 1, 32), err)
 want = np.asarray(g).mean(0)
 got = np.asarray(red)
@@ -93,8 +95,9 @@ def test_multidevice_compressed_mean():
     res = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS],
         capture_output=True, text=True,
-        env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo", timeout=300,
+        env={**os.environ, "PYTHONPATH": "src"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        timeout=300,
     )
     assert res.returncode == 0, res.stderr[-2000:]
     assert "OK" in res.stdout

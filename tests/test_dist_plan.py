@@ -4,21 +4,23 @@ no plan is active."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.dist.plan import (
     ShardingPlan,
-    abstract_mesh,
     constrain,
     current_act_specs,
     current_plan,
+    make_mesh,
+    shard_local,
     use_plan,
 )
 
 
 def _plan(tag="data"):
-    mesh = abstract_mesh((2, 4), ("pod", tag))
+    mesh = AbstractMesh((2, 4), ("pod", tag))
     return ShardingPlan(mesh=mesh, dp=("pod", tag), fsdp=(tag,), tp=tag,
                         ep=(tag,))
 
@@ -91,7 +93,7 @@ class TestConstrainNoOp:
 
     def test_constraint_applies_on_real_mesh(self):
         # conftest forces 8 host devices, so a real (8,)-mesh exists here
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         plan = ShardingPlan(mesh=mesh, dp=("data",), fsdp=("data",),
                             tp="data", ep=("data",))
         with use_plan(plan, {"residual": P("data")}):
@@ -101,3 +103,40 @@ class TestConstrainNoOp:
         with use_plan(plan, {"residual": P("data")}):
             out = jax.jit(lambda x: constrain(x, "residual"))(jnp.arange(7.0))
         assert out.shape == (7,)
+
+
+def test_make_mesh_has_auto_axes():
+    mesh = make_mesh((2, 4), ("pod", "data"))
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+    assert mesh.devices.size == 8
+
+
+class TestShardLocal:
+    def _plan(self):
+        mesh = make_mesh((8,), ("data",))
+        return ShardingPlan(mesh=mesh, dp=("data",), fsdp=("data",), tp=None)
+
+    def test_plain_call_without_plan(self):
+        x = jnp.arange(16.0).reshape(8, 2)
+        jaxpr = jax.make_jaxpr(
+            lambda x: shard_local(lambda a: a * 2, (x,), (("dp", None),), ("dp", None))
+        )(x)
+        assert "shard_map" not in str(jaxpr)
+
+    @pytest.mark.parametrize("rows", [16, 6], ids=["divisible", "indivisible"])
+    def test_matches_the_plain_call_per_shard(self, rows):
+        """A row-local function gives the plain call's result whether the batch
+        splits over the 8 devices or (6 rows) has to stay whole on each."""
+        x = jnp.arange(rows * 3.0).reshape(rows, 3)
+
+        def row_norm(a):  # needs only its own rows
+            return a / jnp.sum(a, axis=1, keepdims=True)
+
+        with use_plan(self._plan()):
+            jaxpr = jax.make_jaxpr(lambda a: shard_local(
+                row_norm, (a,), (("dp", None),), ("dp", None)))(x)
+            got = jax.jit(lambda a: shard_local(
+                row_norm, (a,), (("dp", None),), ("dp", None)))(x)
+        assert "shard_map" in str(jaxpr)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(row_norm(x)),
+                                   rtol=1e-6)

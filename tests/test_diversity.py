@@ -129,3 +129,20 @@ class TestResetAndState:
         assert float(st_.sq_norm_sum) == 0.0
         assert float(st_.sample_count) == 0.0
         assert np.all(np.asarray(st_.grad_sum["w"]) == 0)
+
+    def test_reset_keeps_each_leaf_sharding(self):
+        """A reset under a sharded state leaves every accumulator where it
+        was: the next step's executable is compiled for that layout."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from repro.dist.plan import make_mesh
+
+        mesh = make_mesh((8,), ("data",))
+        st_ = diversity.accumulate(diversity.init_state({"w": jnp.ones(16)}),
+                                   {"w": jnp.ones(16)}, 4, None)
+        st_ = st_._replace(grad_sum={"w": jax.device_put(
+            st_.grad_sum["w"], NamedSharding(mesh, P("data")))})
+        reset = diversity.reset_state(st_)
+        for before, after in zip(jax.tree.leaves(st_), jax.tree.leaves(reset)):
+            assert after.sharding == before.sharding
+        assert np.all(np.asarray(reset.grad_sum["w"]) == 0)

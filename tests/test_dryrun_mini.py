@@ -16,14 +16,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, SHAPES, input_specs
 from repro.configs.base import ShapeConfig
 from repro.dist import sharding as shd
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.models import transformer as tf
 from repro.optim import sgd
 from repro.train.state import init_state
 from repro.train.engine import StepEngine
 from repro.utils import hlo as hlo_lib
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 plan = ShardingPlan(mesh=mesh, dp=("pod", "data"), fsdp=("pod", "data"),
                     tp="model", ep=("pod", "data"))
 

@@ -15,7 +15,7 @@ from repro.ckpt import CheckpointManager
 from repro.core import AdaptiveBatchController, make_policy
 from repro.core.batch_policy import num_buckets
 from repro.data import sigmoid_synthetic
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.elastic import MeshLadder, place, reshard, same_plan
 from repro.models import small
 from repro.optim import sgd
@@ -171,7 +171,7 @@ def _run(mode, epochs=5, prefetch=True):
     train, val, _ = sigmoid_synthetic(n=N, d=D, seed=SEED)
     ladder = MeshLadder(granule=16) if mode == "elastic" else None
     if mode == "full":
-        ctx = use_plan(ShardingPlan(mesh=jax.make_mesh((8,), ("data",))))
+        ctx = use_plan(ShardingPlan(mesh=make_mesh((8,), ("data",))))
     else:
         ctx = contextlib.nullcontext()
     with ctx:
@@ -273,7 +273,7 @@ def test_elastic_init_does_not_donate_caller_params():
 def test_elastic_under_ambient_plan_raises():
     train, val, _ = sigmoid_synthetic(n=256, d=16, seed=0)
     fns = ModelFns(batch_loss=small.logreg_batch_loss)
-    with use_plan(ShardingPlan(mesh=jax.make_mesh((8,), ("data",)))):
+    with use_plan(ShardingPlan(mesh=make_mesh((8,), ("data",)))):
         with pytest.raises(ValueError, match="ambig"):
             Trainer(fns, small.logreg_init(jax.random.key(0), 16), sgd(),
                     _controller(), train, val, estimator="none",
@@ -296,7 +296,7 @@ class TestCheckpointAcrossPlans:
                            elastic=elastic)
 
     def _dp8(self):
-        return ShardingPlan(mesh=jax.make_mesh((8,), ("data",)))
+        return ShardingPlan(mesh=make_mesh((8,), ("data",)))
 
     def test_save_unsharded_restore_dp8_and_reverse(self, tmp_path):
         """A checkpoint is topology-free: save under no plan -> restore under

@@ -356,7 +356,7 @@ def test_untiered_build_rejects_tier():
 def test_trainer_under_dist_plan_matches_unsharded():
     """The same Trainer/engine code runs under a dist plan (dp-sharded
     batches on the 8-device test mesh) with an equivalent trajectory."""
-    from repro.dist.plan import ShardingPlan, use_plan
+    from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 
     train, val, _ = sigmoid_synthetic(n=1024, d=16, seed=0)
     fns = ModelFns(batch_loss=small.logreg_batch_loss,
@@ -374,7 +374,7 @@ def test_trainer_under_dist_plan_matches_unsharded():
     import contextlib as _ctl
     _null = _ctl.nullcontext
     base = run(None)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     sharded = run(ShardingPlan(mesh=mesh))
     assert [h.batch_size for h in base] == [h.batch_size for h in sharded]
     np.testing.assert_allclose([h.val_loss for h in base],

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import default_interpret, ops, ref
 from repro.kernels.psgn import psgn_direct, psgn_gram
 from repro.kernels.quant import dequantize_int8, quantize_int8
 
@@ -334,7 +334,7 @@ def test_psgn_fused_matches_per_layer():
 def test_default_interpret_and_none_flag():
     """Off-TPU the lane defaults to interpret mode, and interpret=None
     resolves through it (satellite: no more hard-coded interpret=True)."""
-    assert ops.default_interpret() is (jax.default_backend() != "tpu")
+    assert default_interpret() is (jax.default_backend() != "tpu")
     x = _rand((2, 20, 12), jnp.float32)
     d = _rand((2, 20, 8), jnp.float32)
     got = ops.persample_sq_norm(x, d, interpret=None)

@@ -11,9 +11,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.models import moe as moe_lib
+from repro.dist.plan import make_mesh
 from repro.models.moe_ep import moe_apply_ep
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 key = jax.random.key(0)
 d, ff, E, topk = 32, 64, 8, 2
 params = moe_lib.moe_init(key, d, ff, E)

@@ -4,6 +4,7 @@ diversity-bound signal/combinator, and degrade-don't-restart supervision."""
 
 import contextlib
 import dataclasses
+import os
 import subprocess
 import sys
 
@@ -23,7 +24,7 @@ from repro.adapt import (
     read_signals,
 )
 from repro.data import sigmoid_synthetic
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.models import small
 from repro.obs.runlog import RunLog, read_runlog
 from repro.optim import sgd
@@ -292,7 +293,7 @@ class TestBoundedRung:
 def _run(mode, epochs=3):
     if mode == "full":
         elastic, ctx = None, use_plan(
-            ShardingPlan(mesh=jax.make_mesh((8,), ("data",))))
+            ShardingPlan(mesh=make_mesh((8,), ("data",))))
     else:
         elastic = PodLadder(pods=2, granule=16,
                             compress=(mode == "compressed"))
@@ -424,12 +425,13 @@ def test_supervisor_cli_multi_pod_demotion(tmp_path):
     written by the child proves it."""
     runlog = str(tmp_path / "runlog.jsonl")
     res = subprocess.run(
-        [sys.executable, "-m", "repro.launch.supervisor",
+        [sys.executable, "-m", "repro.launch.supervisor", "--devices", "8",
          "--epochs", "4", "--pods", "2", "--lose-pod", "2",
          "--ckpt-dir", str(tmp_path / "ckpt"), "--runlog", runlog],
         capture_output=True, text=True,
-        env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo", timeout=300,
+        env={**os.environ, "PYTHONPATH": "src"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        timeout=300,
     )
     assert res.returncode == 0, res.stderr[-2000:]
     assert "completed 4 epochs" in res.stdout

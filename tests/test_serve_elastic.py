@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
-from repro.dist.plan import ShardingPlan, use_plan
+from repro.dist.plan import ShardingPlan, make_mesh, use_plan
 from repro.elastic import MeshLadder
 from repro.models import transformer as tf
 from repro.serve import Request, ServeEngine, padded_prompt_len
@@ -104,7 +104,7 @@ def test_single_device_matches_oracle_with_cache_accounting(golden):
 
 def test_fixed_full_mesh_matches_oracle(golden):
     reqs, expected = golden
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     with use_plan(ShardingPlan(mesh=mesh, tp=None)):
         eng = ServeEngine(CFG, PARAMS, max_slots=8, max_seq=MAX_SEQ,
                           prompt_granule=GRANULE)
@@ -160,7 +160,7 @@ def test_elastic_live_rung_transitions_golden(golden):
 
 
 def test_elastic_under_ambient_plan_raises():
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     with use_plan(ShardingPlan(mesh=mesh, tp=None)):
         with pytest.raises(ValueError, match="ambig"):
             ServeEngine(CFG, PARAMS, elastic=MeshLadder(granule=1))
@@ -332,3 +332,29 @@ def test_every_rung_matches_oracle_pallas(golden):
             eng = ServeEngine(CFG, PARAMS, max_slots=4, max_seq=MAX_SEQ,
                               prompt_granule=GRANULE, attn_impl="pallas")
             assert _tokens(eng.generate(reqs)) == expected, f"rung dp{rung.dp}"
+
+
+def test_elastic_pallas_traces_kernels_under_the_rung_plan(golden, monkeypatch):
+    """On an elastic rung the engine traces under that rung's plan, so every
+    Pallas kernel goes through dist.plan.shard_local on the rung's mesh: the
+    TPU compiler refuses to partition a Mosaic kernel itself, which interpret
+    mode on the CPU would never show."""
+    from repro.dist.plan import current_plan
+
+    seen = set()
+    real = tf.shard_local
+
+    def spy(fn, args, in_roles, out_roles):
+        plan = current_plan()
+        seen.add((fn.func.__name__, plan.mesh.size if plan is not None else 1))
+        return real(fn, args, in_roles, out_roles)
+
+    monkeypatch.setattr(tf, "shard_local", spy)
+    reqs, expected = golden
+    eng = ServeEngine(CFG, PARAMS, max_slots=8, max_seq=MAX_SEQ,
+                      prompt_granule=GRANULE, attn_impl="pallas",
+                      elastic=MeshLadder(granule=1))
+    assert _tokens(eng.generate(reqs)) == expected
+    assert eng.stats.reshards >= 1
+    # prefill chunks and decode steps alike, traced on the 8-device rung
+    assert {("chunk_attention", 8), ("paged_decode_attention", 8)} <= seen

@@ -8,20 +8,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.dist.plan import ShardingPlan, abstract_mesh
+from repro.dist.plan import ShardingPlan
 from repro.dist.sharding import _fit_axes, batch_pspecs, cache_pspecs, infer_pspecs
 from repro.models import transformer as tf
 
 
 def _plan(multi=False):
     if multi:
-        mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+        mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
         return ShardingPlan(mesh=mesh, dp=("pod", "data"), fsdp=("pod", "data"),
                             tp="model", ep=("pod", "data"))
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     return ShardingPlan(mesh=mesh, dp=("data",), fsdp=("data",), tp="model",
                         ep=("data",))
 
