@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the chip, in
+percent (averaged over the chips used)."""
+
+
+def read(rec):
+    tr = rec.get("trace")
+    if not tr or "steps" not in rec:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

@@ -1,0 +1,21 @@
+"""The whole serving step's share of the chip's peak: over the window's
+steps, the sum of the least time each program needs (a decode step reads all
+weights and the live KV; a prefill chunk computes its positions), each the
+larger of operations over the bf16 peak and bytes over HBM bandwidth, over
+the window, in percent."""
+
+from chipbench import flops
+
+
+def read(rec):
+    work = rec.get("step_work")
+    if not work:
+        return None
+    d, peak = rec["dims"], rec["peak"]
+    need = 0.0
+    for s in work:
+        if s["contexts"]:
+            need += flops.least_seconds(*flops.decode_step(d, s["contexts"]), peak)[0]
+        for chunk, prior in s["chunks"]:
+            need += flops.least_seconds(*flops.prefill_chunk(d, chunk, prior), peak)[0]
+    return 100.0 * need / rec["window_s"]
