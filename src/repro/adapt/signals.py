@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as trace_lib
 from repro.utils import pytree as ptu
 
 EPS = 1e-20
@@ -222,6 +223,7 @@ def read_signals(
     loss: float | None = None,
     throughput: float | None = None,
     event: str | None = None,
+    tracer=trace_lib.NULL,
 ) -> tuple[Signals, Any]:
     """Read boundary signals off a ``TrainState``'s diversity accumulators.
 
@@ -229,15 +231,21 @@ def read_signals(
     freshly-zeroed accumulators (the epoch-boundary semantics), with
     ``reset=False`` the state is unchanged (mid-epoch ticks observe the
     running window).  Exactly ONE device->host transfer regardless of how
-    many scalars are read (they come back stacked).
+    many scalars are read (they come back stacked).  ``tracer`` records a
+    ``read_signals`` span holding ``signals_reset`` (the reset's dispatch)
+    and ``signals_transfer`` (the read, which waits for the device).
     """
     from repro.core import diversity  # deferred: see _read_jit
 
-    scalars = _read_jit(estimator)(state.div_state)
-    if reset:
-        # eager, so the zeroed accumulators keep the state's sharding
-        state = state._replace(div_state=diversity.reset_state(state.div_state))
-    vals = np.asarray(scalars)  # the single host transfer
+    with tracer.span("read_signals", reset=reset):
+        scalars = _read_jit(estimator)(state.div_state)
+        if reset:
+            # eager, so the zeroed accumulators keep the state's sharding
+            with tracer.span("signals_reset"):
+                state = state._replace(
+                    div_state=diversity.reset_state(state.div_state))
+        with tracer.span("signals_transfer"):
+            vals = np.asarray(scalars)  # the single host transfer
     sig = Signals(
         diversity=float(vals[0]),
         gns=float(vals[1]),

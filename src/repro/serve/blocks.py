@@ -44,8 +44,6 @@ from __future__ import annotations
 import collections
 from typing import Iterable, Sequence
 
-from repro.obs import trace as trace_lib
-
 Key = tuple
 
 
@@ -73,10 +71,6 @@ def chain_keys(tokens: Sequence[int], block_size: int) -> list[Key]:
 class BlockPool:
     """Host accounting for a ``num_blocks``-block device pool (id 0 reserved
     as the sentinel)."""
-
-    #: telemetry sink (the owning ServeEngine rebinds its own tracer here so
-    #: alloc/evict instants share the decode timeline); stays jax-free
-    tracer = trace_lib.NULL
 
     def __init__(self, num_blocks: int, block_size: int):
         if num_blocks < 2:
@@ -163,14 +157,10 @@ class BlockPool:
         elif self._lru:
             bid, _ = self._lru.popitem(last=False)  # evict least-recently cached
             del self._by_key[self._key_of.pop(bid)]
-            if self.tracer.enabled:
-                self.tracer.instant("pool_evict", bid=bid, cached=len(self._lru))
         else:
             raise PoolExhausted("pool exhausted (no free or evictable block)")
         self._ref[bid] = 1
         self.peak_live = max(self.peak_live, len(self._ref))
-        if self.tracer.enabled:
-            self.tracer.instant("pool_alloc", bid=bid, live=len(self._ref))
         return bid
 
     def retain(self, bid: int) -> None:

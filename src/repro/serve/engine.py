@@ -201,7 +201,7 @@ class ServeStats(metrics_lib.StatsView):
     )
     _GAUGES = (
         "cow_copies", "pool_blocks", "peak_blocks", "block_size",
-        "retired", "compile_s", "dispatch_wall_s", "tokens_per_sec",
+        "retired", "compile_s", "tokens_per_sec",
     )
 
     def __init__(self, donate: bool = True, pool_blocks: int = 0,
@@ -220,8 +220,7 @@ class ServeStats(metrics_lib.StatsView):
             "aux_compiles", "steps", "slot_steps", "tokens", "prefills",
             "prefill_chunks", "shared_prefill_hits", "shared_blocks",
             "cow_copies", "pool_blocks", "peak_blocks", "block_size",
-            "retired", "reshards", "resizes", "compile_s",
-            "dispatch_wall_s", "tokens_per_sec",
+            "retired", "reshards", "resizes", "compile_s", "tokens_per_sec",
         )}
         d["donate"] = self.donate
         d["buckets"] = list(self.buckets)
@@ -350,11 +349,10 @@ class ServeEngine:
             block_size=self.block_size,
         )
         self._thru = ThroughputWindow()
-        # telemetry sinks (repro.obs); the pool shares the engine's tracer so
-        # alloc/evict instants land on the same timeline as decode spans
+        # telemetry sinks (repro.obs); every event of one request carries
+        # its rid: submit, admit, prefill_chunk, first_token, retire
         self.tracer = tracer if tracer is not None else trace_lib.NULL
         self.runlog = runlog if runlog is not None else runlog_lib.NULL
-        self.pool.tracer = self.tracer
         #: emit a ``serve_window`` run-log event every this many decode steps
         self.obs_window = int(obs_window)
 
@@ -576,6 +574,8 @@ class ServeEngine:
                 f"{self.pool.num_blocks - 1}; raise pool_blocks"
             )
         rid = self.sched.submit(request, budget=budget)
+        if self.tracer.enabled:
+            self.tracer.instant("submit", rid=rid, prompt_len=len(prompt))
         self._req_blocks[rid] = _BlockState(
             tokens=padded, plen=plen, budget=budget, nb_prompt=nb_prompt,
             total_need=total_need,
@@ -675,7 +675,9 @@ class ServeEngine:
         self.stats.prefills += 1
         self.stats.shared_prefill_hits += 1
         self._count_token(1)
-        done = self.sched.record(adm.slot, int(np.asarray(tok)[0]))
+        done = self.sched.record(adm.slot, int(jax.device_get(tok)[0]))
+        if self.tracer.enabled:
+            self.tracer.instant("first_token", rid=adm.rid)
         if done:
             self._release(adm.rid)
 
@@ -822,6 +824,13 @@ class ServeEngine:
         bs = self._req_blocks[job.rid]
         self._jobs.remove(job)
         bs.pos = bs.plen
+        # the device wait for the prompt's last chunk (its logits come with it)
+        tr = self.tracer
+        if tr.enabled:
+            with tr.span("prefill_read", rid=job.rid):
+                first = int(jax.device_get(tok)[0])
+        else:
+            first = int(jax.device_get(tok)[0])
         if self.prefix_sharing and bs.keys:
             for key, bid in zip(bs.keys, bs.table[:bs.nb_prompt]):
                 self.pool.register(key, bid)  # first writer wins
@@ -831,7 +840,7 @@ class ServeEngine:
                     "ids": ids,
                     "row": job.row,
                     # host copy: rung-independent, tiny (1 x vocab)
-                    "logits": np.asarray(logits),
+                    "logits": jax.device_get(logits),
                 }
                 while len(self._prompt_cache) > self._prompt_cache_cap:
                     self._prompt_cache.popitem(last=False)
@@ -839,7 +848,9 @@ class ServeEngine:
         self._insert(slot, job.row)
         self.stats.prefills += 1
         self._count_token(1)
-        done = self.sched.record(slot, int(np.asarray(tok)[0]))
+        done = self.sched.record(slot, first)
+        if tr.enabled:
+            tr.instant("first_token", rid=job.rid)
         if done:
             self._release(job.rid)
 
@@ -869,6 +880,8 @@ class ServeEngine:
         blocks fall back to the evictable prefix cache) and return unspent
         reservation credits."""
         bs = self._req_blocks.pop(rid)
+        if self.tracer.enabled:
+            self.tracer.instant("retire", rid=rid, pos=bs.pos)
         for b in bs.table:
             self.pool.release(b)
         if bs.reserved:
@@ -915,12 +928,26 @@ class ServeEngine:
         policy observe -> resize -> reshard -> admit/prefill-chunks) plus
         one decode step over the slot table.  Returns False once fully
         drained."""
-        sch = self.sched
-        if not sch.has_work:
+        if not self.sched.has_work:
             # a drained engine starts the next trace fresh: a stale shrink
             # streak would defeat shrink_patience on its first dip
             self._shrink_streak = 0
             return False
+        tr = self.tracer
+        # disabled path: one attribute load + branch, no clock read
+        if tr.enabled:
+            chunks = self.stats.prefill_chunks
+            with tr.span("serve_step", step_num=self.stats.steps) as span:
+                live = self._step()
+                span.set(bucket=self._bucket, live=live,
+                         prefill_chunks=self.stats.prefill_chunks - chunks)
+        else:
+            self._step()
+        return True
+
+    def _step(self) -> int:
+        """The body of :meth:`step`; returns the number of lanes decoded."""
+        sch = self.sched
         self._observe_policy()
         target = self._target_slots()
         if 0 < target < self._bucket:
@@ -936,7 +963,7 @@ class ServeEngine:
         self.stats.retired = sch.retired  # prefill-instant retirements count
         running = sch.running_slots()
         if not running:  # nothing decoding (drained, or all mid-prefill)
-            return True
+            return 0
         toks = sch.next_tokens()[:, None]
         rids = sch.slot_rids()
         tables = self._decode_tables(running)
@@ -952,21 +979,21 @@ class ServeEngine:
             kind="decode",
         )
         tr = self.tracer
-        t0 = time.perf_counter()
-        # disabled path: one attribute load + branch, no extra transfers
-        # (the per-step (B,) token read below predates the tracer)
+        # the step's one host transfer, a (B,) vector, is also its one wait
+        # for the device: the read drains the queue
         if tr.enabled:
             with tr.span("decode", bucket=self._bucket, rung=self._rung_token,
-                         live=len(running), step_num=self.stats.steps):
+                         live=len(running)):
                 nxt, self._cache, self._pages = exe(
                     self.params, self._cache, self._pages, tables, toks, rids
                 )
+            with tr.span("token_read"):
+                nxt = jax.device_get(nxt)
         else:
             nxt, self._cache, self._pages = exe(
                 self.params, self._cache, self._pages, tables, toks, rids
             )
-        self.stats.dispatch_wall_s += time.perf_counter() - t0
-        nxt = np.asarray(nxt)  # the per-step host transfer: one (B,) vector
+            nxt = jax.device_get(nxt)
         self.stats.steps += 1
         self.stats.slot_steps += self._bucket
         for slot, rid in running:
@@ -983,7 +1010,7 @@ class ServeEngine:
                 live_blocks=self.pool.live, bucket=self._bucket,
                 rung=self._rung_token,
             )
-        return True
+        return len(running)
 
     def drain(self) -> None:
         while self.step():

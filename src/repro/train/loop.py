@@ -419,7 +419,7 @@ class Trainer:
         sig, self.state = read_signals(
             self.state, self._read_estimator(), reset=False,
             batch_size=bsz, loss=last_loss,
-            throughput=self._throughput(), event=event,
+            throughput=self._throughput(), event=event, tracer=self._tracer,
         )
         applied = self.adapt.observe(sig, clock)
         if applied is not None:
@@ -436,7 +436,7 @@ class Trainer:
         sig, self.state = read_signals(
             self.state, self._read_estimator(), reset=True,
             batch_size=bsz, loss=mean_loss,
-            throughput=self._throughput(),
+            throughput=self._throughput(), tracer=self._tracer,
         )
         if self.estimator == "oracle":
             sig = dataclasses.replace(sig, diversity=self._oracle_diversity())

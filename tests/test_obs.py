@@ -149,8 +149,8 @@ class TestStatsViews:
         "aux_compiles", "steps", "slot_steps", "tokens", "prefills",
         "prefill_chunks", "shared_prefill_hits", "shared_blocks",
         "cow_copies", "pool_blocks", "peak_blocks", "block_size", "retired",
-        "reshards", "resizes", "compile_s", "dispatch_wall_s",
-        "tokens_per_sec", "donate", "buckets", "rungs",
+        "reshards", "resizes", "compile_s", "tokens_per_sec", "donate",
+        "buckets", "rungs",
     ]
 
     def test_engine_stats_registry_equivalence(self):
@@ -257,11 +257,90 @@ class TestTraceSchema:
         path = tr.save(str(tmp_path))  # directory -> <dir>/trace.json
         assert path == str(tmp_path / "trace.json")
         doc = json.loads((tmp_path / "trace.json").read_text())
-        names = [e["name"] for e in doc["traceEvents"]]
+        names = [e["name"] for e in doc["traceEvents"] if e["name"] != "gc"]
         # inner exits first; one thread_name metadata record per thread
         assert names == ["thread_name", "inner", "outer", "mark"]
         assert doc["traceEvents"][0]["args"]["name"] == \
             threading.current_thread().name
+
+    def test_origin_maps_onto_perf_counter(self):
+        tr = Tracer()
+        before = time.perf_counter()
+        with tr.span("s"):
+            pass
+        after = time.perf_counter()
+        ev = next(e for e in tr.events if e["name"] == "s")
+        start = (tr.origin_ns + ev["ts"] * 1e3) * 1e-9
+        assert before - 1e-3 <= start <= after + 1e-3
+        assert abs(start - before) < 1e-3
+        other = tr.to_json()["otherData"]
+        assert other["perf_counter_origin_ns"] == tr.origin_ns
+
+    def test_set_adds_args_at_exit(self):
+        tr = Tracer()
+        with tr.span("s", a=1) as sp:
+            sp.set(b=2)
+        assert tr.events[-1]["args"] == {"a": 1, "b": 2}
+
+    def test_only_the_outermost_step_span_marks_a_profiler_step(self):
+        """Spans tagged ``step_num`` inside an open step become plain
+        annotations, so the profiler sees one step per engine step."""
+        made = []
+
+        class Ann:
+            def __init__(self, kind, name):
+                made.append((kind, name))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        class FakeProfiler:
+            def StepTraceAnnotation(self, name, step_num):
+                return Ann("step", name)
+
+            def TraceAnnotation(self, name):
+                return Ann("plain", name)
+
+        tr = Tracer()
+        tr._profiler = FakeProfiler()
+        for i in range(2):
+            with tr.span("serve_step", step_num=i):
+                with tr.span("observe", step_num=i):
+                    pass
+        assert made == [("step", "serve_step"), ("plain", "observe")] * 2
+
+    def test_collections_are_gc_spans(self):
+        import gc
+        hooks = len(gc.callbacks)
+        tr = Tracer()
+        assert len(gc.callbacks) == hooks + 1
+        gc.collect()
+        evs = [e for e in tr.events if e["name"] == "gc"]
+        assert evs and evs[-1]["args"]["generation"] == 2
+        assert evs[-1]["args"]["collected"] >= 0 and evs[-1]["dur"] > 0
+        del tr, evs
+        assert len(gc.callbacks) == hooks  # a dead tracer's hook is gone
+
+    def test_collection_under_the_tracer_lock_does_not_deadlock(self):
+        """A collection can start while the recording thread holds the
+        lock (inside an append); it is recorded, not a deadlock."""
+        import gc
+        tr = Tracer()
+        done = []
+
+        def work():
+            with tr._lock:
+                gc.collect()
+            done.append(True)
+
+        th = threading.Thread(target=work, daemon=True)
+        th.start()
+        th.join(timeout=30)
+        assert not th.is_alive() and done
+        assert any(e["name"] == "gc" for e in tr.events)
 
     def test_threads_get_own_lanes(self):
         tr = Tracer()
@@ -274,7 +353,8 @@ class TestTraceSchema:
         with tr.span("fg"):
             pass
         evs = tr.events
-        tids = {e["tid"] for e in evs if e["ph"] == "X"}
+        # (a collection is recorded on whichever thread it starts in)
+        tids = {e["tid"] for e in evs if e["ph"] == "X" and e["name"] != "gc"}
         assert len(tids) == 2
         meta = [e for e in evs if e["ph"] == "M"]
         assert {m["args"]["name"] for m in meta} >= {"bg-thread"}
@@ -415,7 +495,9 @@ class TestMonitor:
         # all tracer events + one runlog lane (thread_name + one instant per
         # logged event), aligned via wall_origin
         lane = [e for e in evs if e["tid"] == -1]
-        assert len(evs) == len(tracer.events) + len(lane)
+        # the saved trace: a live tracer goes on recording collections
+        saved = json.loads(open(f"{run_dir}/trace.json").read())["traceEvents"]
+        assert len(evs) == len(saved) + len(lane)
         assert lane[0]["args"]["name"] == "runlog"
         assert len(lane) == 1 + len(monitor.load(run_dir))
         assert all(e["ph"] == "i" for e in lane[1:])
@@ -425,19 +507,32 @@ class TestMonitor:
 # serve instrumentation
 
 
+def _serve_cfg():
+    return ModelConfig(
+        name="t", family="dense", num_layers=2, d_model=32, num_heads=4,
+        num_kv_heads=2, d_ff=64, vocab_size=61, pattern=("attn",),
+        param_dtype="float32", compute_dtype="float32", xent_chunk=8,
+        remat=False,
+    )
+
+
+def _serve_requests(seed, lens=(20, 27, 12), new=(8, 6, 8)):
+    rng = np.random.default_rng(seed)
+    return [Request(prompt=rng.integers(1, 61, size=n).astype(np.int32),
+                    max_new_tokens=m) for n, m in zip(lens, new)]
+
+
+def _inside(child, parent):
+    return (child["tid"] == parent["tid"] and child["ts"] >= parent["ts"]
+            and child["ts"] + child["dur"]
+            <= parent["ts"] + parent["dur"] + 0.01)
+
+
 class TestServeObs:
     def test_serve_spans_and_events(self, tmp_path):
-        cfg = ModelConfig(
-            name="t", family="dense", num_layers=2, d_model=32, num_heads=4,
-            num_kv_heads=2, d_ff=64, vocab_size=61, pattern=("attn",),
-            param_dtype="float32", compute_dtype="float32", xent_chunk=8,
-            remat=False,
-        )
+        cfg = _serve_cfg()
         params = tf.init_params(cfg, jax.random.key(0))
-        rng = np.random.default_rng(7)
-        reqs = [Request(prompt=rng.integers(1, cfg.vocab_size, size=n)
-                        .astype(np.int32), max_new_tokens=m)
-                for n, m in zip((20, 27, 12), (8, 6, 8))]
+        reqs = _serve_requests(7)
         tracer = Tracer()
         log = RunLog(str(tmp_path))
         eng = ServeEngine(cfg, params, max_slots=4, max_seq=64,
@@ -451,8 +546,21 @@ class TestServeObs:
         assert {"admit", "prefill_chunk", "decode", "compile"} <= set(spans)
         assert spans.count("prefill_chunk") == eng.stats.prefill_chunks
         assert spans.count("decode") == eng.stats.steps
-        # pool churn shows up as instants on the same timeline
-        assert any(e["name"] == "pool_alloc" for e in tracer.events)
+        # one serve_step span per boundary that decoded, around one token read
+        steps = [e for e in tracer.events if e["name"] == "serve_step"]
+        reads = [e for e in tracer.events if e["name"] == "token_read"]
+        decoding = [s for s in steps if s["args"]["live"] > 0]
+        assert len(decoding) == eng.stats.steps == len(reads)
+        assert [s["args"]["step_num"] for s in decoding] == \
+            list(range(eng.stats.steps))
+        for s in steps:
+            mine = [r for r in reads if _inside(r, s)]
+            assert len(mine) == (1 if s["args"]["live"] else 0), s
+        assert sum(s["args"]["prefill_chunks"] for s in steps) == \
+            eng.stats.prefill_chunks
+        # the profiler's step is the serve_step, not the decode inside it
+        assert all("step_num" not in e["args"] for e in tracer.events
+                   if e["name"] == "decode")
 
         evs = read_runlog(str(tmp_path))
         kinds = [e["kind"] for e in evs]
@@ -476,6 +584,30 @@ class TestServeObs:
         assert st.namespace.startswith("serve.engine.")
         for f in (*st._COUNTERS, *st._GAUGES):
             assert snap[f"{st.namespace}.{f}"] == getattr(st, f), f
+
+    def test_request_lifecycle(self):
+        """Every event of one request carries its rid: submit, admit,
+        first_token and retire once each, in that order."""
+        cfg = _serve_cfg()
+        params = tf.init_params(cfg, jax.random.key(0))
+        tracer = Tracer()
+        eng = ServeEngine(cfg, params, max_slots=2, max_seq=64,
+                          prompt_granule=8, prefill_chunk=8, tracer=tracer)
+        rids = [eng.submit(r) for r in _serve_requests(9, (20, 27, 12, 20))]
+        eng.drain()
+        kinds = ("submit", "admit", "first_token", "retire")
+        by = {k: [e for e in tracer.events if e["name"] == k] for k in kinds}
+        for k in kinds:
+            assert sorted(e["args"]["rid"] for e in by[k]) == rids, k
+        for rid in rids:
+            ts = [next(e["ts"] for e in by[k] if e["args"]["rid"] == rid)
+                  for k in kinds]
+            assert ts == sorted(ts), (rid, ts)
+        chunks = [e for e in tracer.events if e["name"] == "prefill_chunk"]
+        assert {e["args"]["rid"] for e in chunks} == set(rids)
+        # a finished prompt's first token is a device wait of its own
+        assert len([e for e in tracer.events if e["name"] == "prefill_read"]) \
+            == len(rids)
 
     def test_serve_policy_event(self, tmp_path):
         """An applied ServePolicy decision mirrors into the typed
@@ -516,6 +648,30 @@ class TestServeObs:
 
 
 # ---------------------------------------------------------------------------
+# adaptation instrumentation
+
+
+class TestAdaptObs:
+    def test_read_signals_spans(self):
+        from repro.adapt import read_signals
+        train, val, _ = sigmoid_synthetic(n=512, d=16, seed=0)
+        t = _logreg_trainer(train, val, m0=64, m_max=64)
+        batch = jax.tree.map(jax.numpy.asarray, train.get(np.arange(64)))
+        state, _ = t.engine.step(t.state, batch, 0.5)
+        tr = Tracer()
+        sig, state = read_signals(state, "exact", reset=True, batch_size=64,
+                                  tracer=tr)
+        assert sig.samples == 64
+        spans = {e["name"]: e for e in tr.events
+                 if e["ph"] == "X" and e["name"] != "gc"}
+        assert set(spans) == {"read_signals", "signals_reset",
+                              "signals_transfer"}
+        for child in ("signals_reset", "signals_transfer"):
+            assert _inside(spans[child], spans["read_signals"]), child
+        assert spans["signals_reset"]["ts"] < spans["signals_transfer"]["ts"]
+
+
+# ---------------------------------------------------------------------------
 # overhead guard
 
 
@@ -541,7 +697,50 @@ class TestOverheadGuard:
             eng.tracer = Tracer()
             for _ in range(3):
                 state, _ = eng.step(state, batch, 0.5)
-        assert len([e for e in eng.tracer.events if e["ph"] == "X"]) == 3
+        assert len([e for e in eng.tracer.events
+                    if e["name"] == "dispatch"]) == 3
+
+    def test_serve_step_adds_no_transfer_or_clock_read(self, monkeypatch):
+        """Serving's hot loop reads the device only where it means to (each
+        read explicit, so the guard turns any other into an error), the same
+        reads with the tracer on as off, and with the default sink it reads
+        no clock through the engine or the tracer."""
+        import types
+        from repro.serve import engine as engine_mod
+        cfg = _serve_cfg()
+        params = tf.init_params(cfg, jax.random.key(0))
+        eng = ServeEngine(cfg, params, max_slots=4, max_seq=64,
+                          prompt_granule=8, prefill_chunk=8)
+        assert eng.tracer is trace.NULL
+        eng.generate(_serve_requests(1))  # compile every program first
+        reads, clocks = [], []
+        get = jax.device_get
+        monkeypatch.setattr(jax, "device_get",
+                            lambda x: reads.append(1) or get(x))
+        for mod in (engine_mod, trace):
+            stub = types.SimpleNamespace(
+                time=time.time,
+                perf_counter=lambda: clocks.append(1) or time.perf_counter(),
+                perf_counter_ns=lambda: (clocks.append(1)
+                                         or time.perf_counter_ns()))
+            monkeypatch.setattr(mod, "time", stub)
+
+        def run(seed):
+            del reads[:], clocks[:]
+            s0, p0 = eng.stats.steps, eng.stats.prefills
+            with jax.transfer_guard_device_to_host("disallow"):
+                eng.generate(_serve_requests(seed))
+            # one read per decode step, and per finished prompt its first
+            # token and the logits kept for full-prompt hits; no other
+            assert len(reads) == ((eng.stats.steps - s0)
+                                  + 2 * (eng.stats.prefills - p0))
+            return len(reads), len(clocks)
+
+        off = run(2)
+        assert off[1] == 0
+        eng.tracer = Tracer()
+        on = run(3)
+        assert on[0] == off[0] and on[1] > 0
 
     def test_disabled_path_cost_is_a_sliver_of_a_step(self):
         """Deterministic micro-ratio (no flaky wall A/B: that lives in
