@@ -163,6 +163,13 @@ def constrain(x: jax.Array, name: str) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(plan.mesh, fitted))
 
 
+def maps_shards() -> bool:
+    """Whether :func:`shard_local` runs its function once per shard (a plan
+    over more than one device) rather than as the plain call."""
+    plan = current_plan()
+    return plan is not None and plan.mesh.size > 1
+
+
 def shard_local(fn: Callable, args: tuple, in_roles: tuple, out_roles: tuple):
     """``fn(*args)``, run once per shard of the active plan's mesh.
 
@@ -176,9 +183,9 @@ def shard_local(fn: Callable, args: tuple, in_roles: tuple, out_roles: tuple):
     still correct, at the cost of every shard computing them.  Outside a
     plan, or on a one-device mesh, this is the plain call.
     """
-    plan = current_plan()
-    if plan is None or plan.mesh.size == 1:
+    if not maps_shards():
         return fn(*args)
+    plan = current_plan()
     axes = {"dp": tuple(plan.dp) or None, "tp": plan.tp}
     ok = {role: axes[role] is not None for role in axes}
     for arg, roles in zip(args, in_roles):

@@ -28,7 +28,9 @@ dispatches; ``repro.kernels.default_interpret`` decides interpret mode):
                     (B, n_max*block, KV, hd) gathered context the XLA path
                     builds with ``jnp.take``.  Per-row lengths mask the
                     sentinel/pool tail and ``pl.when`` skips dead table
-                    entries entirely.
+                    entries entirely.  It reads the layer-stacked pool in
+                    place: the layer index is a scalar prefetch too, so the
+                    model's layer scan never slices a layer's pool out.
 
 All kernels pad ragged shapes to block multiples internally (padding is
 masked, outputs sliced); GQA is handled by mapping head h onto KV head
@@ -461,8 +463,9 @@ def chunk_attention(
 # ---------------------------------------------------------------------------
 
 
-def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, blk, n_max, softcap):
+def _paged_decode_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_ref,
+                         v_ref, o_ref, m_ref, l_ref, acc_ref, *, blk, n_max,
+                         softcap):
     b = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -515,39 +518,52 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_decode_attention(
     q: jax.Array,        # (B, 1, H, hd) — this step's query per slot
-    pool_k: jax.Array,   # (num_blocks, block, KV, hd) — the SHARED pool
+    pool_k: jax.Array,   # (L, num_blocks, block, KV, hd) — the SHARED pool,
+                         # stacked over layers; or (num_blocks, block, KV, hd)
     pool_v: jax.Array,
     tables: jax.Array,   # (B, n_max) int32 — slot b's logical block i lives
                          # at pool block tables[b, i]; dead entries sentinel 0
     lengths: jax.Array,  # (B,) int32 — valid context length per slot
+    layer: jax.Array | int | None = None,  # () int32 — which layer of the
+                         # stack to read; None for a 4-D pool
     *,
     softcap: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused paged decode attention: (B, 1, H, hd).
 
-    The XLA lane materialises ``jnp.take(pool, tables)`` — the full
+    The XLA lane materialises ``pool[layer, tables]`` — the full
     (B, n_max*block, KV, hd) gathered context — before attending.  Here the
     gather IS the k/v BlockSpec index_map over the scalar-prefetched table:
-    grid step (b, i) streams pool block ``tables[b, i]`` straight from the
-    pool, so only live blocks are read per row and the gathered context
-    never exists in memory.  Numerics match
+    grid step (b, i) streams block ``tables[b, i]`` of layer ``layer``
+    straight from the stacked pool, so only live blocks are read per row,
+    and neither the gathered context nor the layer's slice of the pool ever
+    exists in memory.  A 4-D pool is a one-layer stack.  Numerics match
     ``models/attention.py::decode_attention`` on the gathered view (same
     scale/softcap/length-mask order, f32 accumulation).
     """
     b, one, h, hd = q.shape
     assert one == 1, q.shape
-    nb, blk, kv, _ = pool_k.shape
+    if pool_k.ndim == 4:
+        assert layer is None, "a 4-D pool has no layer axis"
+        pool_k, pool_v = pool_k[None], pool_v[None]
+    layer = jnp.reshape(jnp.asarray(0 if layer is None else layer, jnp.int32),
+                        (1,))
+    _, nb, blk, kv, _ = pool_k.shape
     n_max = tables.shape[1]
+    pool_spec = pl.BlockSpec(
+        (pl.Squeezed(), 1, blk, kv, hd),
+        lambda b_, i, t_, l_, ly_: (ly_[0], t_[b_, i], 0, 0, 0),
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_max),
         in_specs=[
-            pl.BlockSpec((1, 1, h, hd), lambda b_, i, t_, l_: (b_, 0, 0, 0)),
-            pl.BlockSpec((1, blk, kv, hd), lambda b_, i, t_, l_: (t_[b_, i], 0, 0, 0)),
-            pl.BlockSpec((1, blk, kv, hd), lambda b_, i, t_, l_: (t_[b_, i], 0, 0, 0)),
+            pl.BlockSpec((1, 1, h, hd), lambda b_, i, t_, l_, ly_: (b_, 0, 0, 0)),
+            pool_spec,
+            pool_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, h, hd), lambda b_, i, t_, l_: (b_, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, h, hd), lambda b_, i, t_, l_, ly_: (b_, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -560,4 +576,5 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h, hd), q.dtype),
         interpret=resolve_interpret(interpret),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), q, pool_k, pool_v)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), layer, q, pool_k,
+      pool_v)

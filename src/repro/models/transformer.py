@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.dist.plan import constrain, shard_local
+from repro.dist.plan import constrain, current_plan, maps_shards, shard_local
+from repro.dist.sharding import cache_pspecs, shardings_of
 from repro.kernels import attention as kernels_attn
 from repro.models import attention as attn_lib
 from repro.models import moe as moe_lib
@@ -55,10 +56,11 @@ def _norm(cfg: ModelConfig, params, x):
 
 # Plan roles of each dim for the Pallas kernels (dist.plan.shard_local):
 # (B, S, heads, hd) activations split the batch over dp and heads over tp;
-# the paged KV pool (blocks, block, kv_heads, hd) splits only its heads,
-# since a decode lane may read any block.
+# the paged KV pool, whole and stacked over layers (repeats, blocks, block,
+# kv_heads, hd), splits only its heads, since a decode lane may read any
+# block of any layer.
 _HEADS = ("dp", None, "tp", None)
-_POOL = (None, None, "tp", None)
+_POOL = (None, None, None, "tp", None)
 
 
 def _flash_pallas(cfg: ModelConfig, q, k, v, causal: bool, window, s: int):
@@ -423,9 +425,11 @@ def paged_positions(cfg: ModelConfig) -> tuple[int, ...]:
 
 def init_pages(cfg: ModelConfig, num_blocks: int, block_size: int) -> PyTree:
     """The paged KV pool: per full-attention pattern position, a flat pool of
-    ``num_blocks`` blocks of ``block_size`` token rows, stacked over repeats
-    (same scan layout as the dense cache).  Block 0 is the sentinel — never
-    allocated, the write target of inactive lanes (see serve/blocks.py)."""
+    ``num_blocks`` blocks of ``block_size`` token rows, stacked over repeats.
+    The layer scans of ``decode_step`` and ``prefill_chunk`` carry it whole
+    and update it in place, indexed by layer.  Block 0 is the sentinel —
+    never allocated, the write target of inactive lanes (see
+    serve/blocks.py)."""
     cdt = _cdtype(cfg)
     hd = cfg.resolved_head_dim
     return {
@@ -463,7 +467,10 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
     ``tables[b, i]`` — so each row writes its current token at
     ``tables[b, pos // block]`` offset ``pos % block`` and reads its whole
     context through a table gather.  Inactive lanes point at sentinel block
-    0 (written garbage, masked by the validity count on read)."""
+    0 (written garbage, masked by the validity count on read).  The pool
+    rides the layer scan's carry, one buffer for every layer: layer ``l``
+    scatters into ``pages[..][l]`` and reads it in place, so a donated pool
+    is neither sliced per layer nor restacked."""
     cdt = _cdtype(cfg)
     if cfg.input_mode == "tokens":
         x = embed(params["embed"], tokens_or_embs).astype(cdt)
@@ -473,11 +480,11 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
     pos_now = cache["len"]  # () int32, or (B,) int32 per-slot
     per_slot = jnp.ndim(pos_now) == 1
     hd = cfg.resolved_head_dim
-    pages_in = pages if pages is not None else {}
 
-    def layer_body(x, scanned):
-        layer, lcache, lpages = scanned
-        new_cache, new_pages = {}, {}
+    def layer_body(carry, scanned):
+        x, pool = carry
+        l, layer, lcache = scanned
+        new_cache, pool = {}, dict(pool)
         for p in range(cfg.period):
             kind = cfg.pattern[p]
             blk = layer[f"pos{p}"]
@@ -488,7 +495,7 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
                     d_state=cfg.ssm_state, dt_rank=cfg.dt_rank,
                 )
                 new_cache[f"pos{p}"] = new_state
-            elif f"pos{p}" in pages_in:
+            elif f"pos{p}" in pool:
                 ap = blk["attn"]
                 q = dense(ap["q"], h).reshape(b, 1, cfg.num_heads, hd)
                 k = dense(ap["k"], h).reshape(b, 1, cfg.num_kv_heads, hd)
@@ -497,32 +504,46 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
                         else jnp.full((b,), pos_now, jnp.int32))
                 q = apply_rope(q, posv[:, None], cfg.rope_theta)
                 k = apply_rope(k, posv[:, None], cfg.rope_theta)
-                pk, pv = lpages[f"pos{p}"]["k"], lpages[f"pos{p}"]["v"]
-                blk_sz = pk.shape[1]
+                pk, pv = pool[f"pos{p}"]["k"], pool[f"pos{p}"]["v"]
+                blk_sz = pk.shape[2]
                 rows = jnp.arange(b)
                 wb = tables[rows, posv // blk_sz]  # (B,) pool block per row
                 off = jnp.mod(posv, blk_sz)
-                pk = pk.at[wb, off].set(k[:, 0])
-                pv = pv.at[wb, off].set(v[:, 0])
+                pk = pk.at[l, wb, off].set(k[:, 0])
+                pv = pv.at[l, wb, off].set(v[:, 0])
                 # write-then-read: this token is visible to its own query
                 if cfg.attn_impl == "pallas":
                     # fused lane: the table gather happens inside the kernel's
                     # KV loop — the (B, n_max*block, KV, hd) gathered context
-                    # below never materialises
+                    # below never materialises — and the kernel reads layer
+                    # l of the stacked pool in place.  Mapped over shards,
+                    # the kernel's pool operand is gathered whole onto every
+                    # shard, so there it is this layer's slice, kept in the
+                    # pool's own sharding (else the slice is cut from a
+                    # gathered stack)
+                    kv_l, at = {"k": pk, "v": pv}, l
+                    if maps_shards():
+                        kv_l = {n: jax.lax.dynamic_slice_in_dim(a, l, 1)
+                                for n, a in kv_l.items()}
+                        plan = current_plan()
+                        kv_l = jax.lax.with_sharding_constraint(
+                            kv_l, shardings_of(cache_pspecs(kv_l, plan), plan))
+                        at = jnp.zeros((), jnp.int32)
                     h = shard_local(
                         functools.partial(kernels_attn.paged_decode_attention,
                                           softcap=cfg.attn_softcap),
-                        (q, pk, pv, tables, posv + 1),
-                        (_HEADS, _POOL, _POOL, ("dp", None), ("dp",)), _HEADS,
+                        (q, kv_l["k"], kv_l["v"], tables, posv + 1, at),
+                        (_HEADS, _POOL, _POOL, ("dp", None), ("dp",), None),
+                        _HEADS,
                     )
                 else:
-                    gk = jnp.take(pk, tables, axis=0).reshape(b, -1, cfg.num_kv_heads, hd)
-                    gv = jnp.take(pv, tables, axis=0).reshape(b, -1, cfg.num_kv_heads, hd)
+                    gk = pk[l, tables].reshape(b, -1, cfg.num_kv_heads, hd)
+                    gv = pv[l, tables].reshape(b, -1, cfg.num_kv_heads, hd)
                     h = attn_lib.decode_attention(
                         q, gk, gv, posv + 1, softcap=cfg.attn_softcap, window=None,
                     )
                 h = dense(ap["o"], h.reshape(b, 1, cfg.num_heads * hd))
-                new_pages[f"pos{p}"] = {"k": pk, "v": pv}
+                pool[f"pos{p}"] = {"k": pk, "v": pv}
             else:
                 ap = blk["attn"]
                 q = dense(ap["q"], h).reshape(b, 1, cfg.num_heads, hd)
@@ -558,12 +579,13 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
                 h = _norm(cfg, blk["ffn_norm"], x)
                 h, _ = _ffn_sublayer(cfg, blk["ffn"], x=h, kind=cfg.ffn_kind(p), moe_groups=moe_groups)
                 x = x + h
-        return x, (new_cache, new_pages)
+        return (x, pool), new_cache
 
     blocks = _blocks(params, cfg)
     layer_caches = {k: v for k, v in cache.items() if k != "len"}
-    x, (new_caches, new_pages) = jax.lax.scan(
-        layer_body, x, (blocks, layer_caches, pages_in)
+    (x, new_pages), new_caches = jax.lax.scan(
+        layer_body, (x, pages if pages is not None else {}),
+        (jnp.arange(cfg.repeats), blocks, layer_caches),
     )
     x = _norm(cfg, params["final_norm"], x)
     logits = (x @ params["lm_head"]["kernel"].astype(x.dtype)).astype(jnp.float32)
@@ -686,7 +708,8 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
         entries (``init_cache(cfg, 1, max_seq, skip=paged_positions(cfg))``
         shapes); full-attention positions have NO row entry, their KV goes
         straight to ``pages`` at ``write_tab``.
-      pages: the block pool (``init_pages`` layout).
+      pages: the block pool (``init_pages`` layout), carried whole through
+        the layer scan and updated in place, as in ``decode_step``.
       batch: ``{"tokens": (1, C)}`` — C a multiple of the block size.
       offset: () int32, this chunk's first absolute position (block-aligned).
       prior_tab: (nbp,) int32 prior prompt blocks in logical order, padded
@@ -723,9 +746,10 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
             softcap=cfg.attn_softcap,
         )
 
-    def layer_body(x, scanned):
-        layer, lrow, lpages = scanned
-        new_row, new_pages = {}, {}
+    def layer_body(carry, scanned):
+        x, pool = carry
+        l, layer, lrow = scanned
+        new_row, pool = {}, dict(pool)
         for p in range(cfg.period):
             kind = cfg.pattern[p]
             blk = layer[f"pos{p}"]
@@ -752,12 +776,25 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
                 v = dense(ap["v"], h).reshape(1, c, cfg.num_kv_heads, hd)
                 q = apply_rope(q, positions, cfg.rope_theta)
                 k = apply_rope(k, positions, cfg.rope_theta)
-                pk, pv = lpages[f"pos{p}"]["k"], lpages[f"pos{p}"]["v"]
-                blk_sz = pk.shape[1]
+                pk, pv = pool[f"pos{p}"]["k"], pool[f"pos{p}"]["v"]
+                blk_sz = pk.shape[2]
+                # write-then-read, as in decode: the chunk's blocks are not
+                # among its prior blocks, so the gather reads what it would
+                # have before the write, and it reads the written pool, so
+                # the write can go in place instead of into a copy.  Rows are
+                # scattered one token at a time, as decode writes them: a
+                # scatter of whole blocks leads the TPU compiler to lay the
+                # pool out anew inside the scan, copying it in and out
+                rows = jnp.arange(c)
+                wb = write_tab[rows // blk_sz]
+                off = jnp.mod(rows, blk_sz)
+                pk = pk.at[l, wb, off].set(k[0])
+                pv = pv.at[l, wb, off].set(v[0])
+                pool[f"pos{p}"] = {"k": pk, "v": pv}
                 np_prior = prior_tab.shape[0]
                 prior = np_prior * blk_sz
-                gk = jnp.take(pk, prior_tab, axis=0).reshape(1, prior, cfg.num_kv_heads, hd)
-                gv = jnp.take(pv, prior_tab, axis=0).reshape(1, prior, cfg.num_kv_heads, hd)
+                gk = pk[l, prior_tab].reshape(1, prior, cfg.num_kv_heads, hd)
+                gv = pv[l, prior_tab].reshape(1, prior, cfg.num_kv_heads, hd)
                 k_pos = jnp.concatenate([jnp.arange(prior), q_pos])
                 k_valid = jnp.concatenate(
                     [jnp.arange(prior) < offset, jnp.ones((c,), bool)]
@@ -768,9 +805,6 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
                     k_pos, k_valid, window=None,
                 )
                 h = dense(ap["o"], h.reshape(1, c, cfg.num_heads * hd))
-                pk = pk.at[write_tab].set(k[0].reshape(-1, blk_sz, cfg.num_kv_heads, hd))
-                pv = pv.at[write_tab].set(v[0].reshape(-1, blk_sz, cfg.num_kv_heads, hd))
-                new_pages[f"pos{p}"] = {"k": pk, "v": pv}
             elif kind == "attn_local":  # prior context from the windowed ring
                 ap = blk["attn"]
                 q = dense(ap["q"], h).reshape(1, c, cfg.num_heads, hd)
@@ -806,12 +840,12 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
                 h = _norm(cfg, blk["ffn_norm"], x)
                 h, _ = _ffn_sublayer(cfg, blk["ffn"], x=h, kind=cfg.ffn_kind(p), moe_groups=moe_groups)
                 x = x + h
-        return x, (new_row, new_pages)
+        return (x, pool), new_row
 
     blocks = _blocks(params, cfg)
     row_layers = {k: v for k, v in row.items() if k != "len"}
-    x, (new_row, new_pages) = jax.lax.scan(
-        layer_body, x, (blocks, row_layers, pages)
+    (x, new_pages), new_row = jax.lax.scan(
+        layer_body, (x, pages), (jnp.arange(cfg.repeats), blocks, row_layers)
     )
     x = _norm(cfg, params["final_norm"], x)
     last = x[:, -1:, :]

@@ -294,6 +294,30 @@ def test_paged_decode_matches_xla_gather_on_real_pool():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("softcap", [None, 10.0])
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_paged_decode_stacked_pool_reads_its_layer(layer, softcap):
+    """The layer-stacked pool with ``layer=l`` is the 4-D call on
+    ``pool[l]``: the kernel reads that layer's blocks and no other's, at the
+    first, a middle and the last layer."""
+    r = np.random.default_rng(101 + layer)
+    n_layers, b, blk, n_max, kv, h, hd = 5, 3, 8, 4, 2, 4, 16
+    nb = n_max * b + 1
+    pool_k = jnp.asarray(r.standard_normal((n_layers, nb, blk, kv, hd)), jnp.float32)
+    pool_v = jnp.asarray(r.standard_normal((n_layers, nb, blk, kv, hd)), jnp.float32)
+    tables = jnp.asarray(r.permutation(np.arange(1, nb))[:b * n_max]
+                         .reshape(b, n_max), jnp.int32)
+    lengths = jnp.asarray([1, 2 * blk + 3, n_max * blk], jnp.int32)
+    q = jnp.asarray(r.standard_normal((b, 1, h, hd)), jnp.float32)
+    got = kattn.paged_decode_attention(q, pool_k, pool_v, tables, lengths,
+                                       jnp.int32(layer), softcap=softcap,
+                                       interpret=True)
+    want = kattn.paged_decode_attention(q, pool_k[layer], pool_v[layer],
+                                        tables, lengths, softcap=softcap,
+                                        interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_flash_kernel_matches_xla_flash():
     """The two tiled lanes (Pallas vs lax.scan flash) agree on a block-
     aligned workload — attn_impl='pallas' is a drop-in for 'flash'."""

@@ -5,6 +5,7 @@ batching retire/refill fix for the old chunked-generate waste; and the
 ring/SSM slot-insertion substrate."""
 
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -358,3 +359,20 @@ def test_elastic_pallas_traces_kernels_under_the_rung_plan(golden, monkeypatch):
     assert eng.stats.reshards >= 1
     # prefill chunks and decode steps alike, traced on the 8-device rung
     assert {("chunk_attention", 8), ("paged_decode_attention", 8)} <= seen
+
+
+def test_elastic_pallas_gathers_one_layer_of_the_pool(golden):
+    """The decode scan carries the whole pool; on a rung's mesh the kernel's
+    pool operand is gathered onto every shard, and what is gathered is one
+    layer's slice per layer, never the stack of every layer."""
+    reqs, expected = golden
+    eng = ServeEngine(CFG, PARAMS, max_slots=8, max_seq=MAX_SEQ,
+                      prompt_granule=GRANULE, attn_impl="pallas",
+                      elastic=MeshLadder(granule=1))
+    assert _tokens(eng.generate(reqs)) == expected
+    stack = eng._pages["pos0"]["k"].shape
+    dims = ",".join(map(str, stack[1:]))
+    texts = [exe.as_text() for key, exe in eng._exes.items() if key[0] == "decode"]
+    gathered = [re.findall(rf"= f32\[(\d+),{dims}\]\S* all-gather\(", t) for t in texts]
+    assert all(n == "1" for g in gathered for n in g), gathered
+    assert any(gathered), "no rung gathered the pool: none was mapped over shards"

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _hlo import pool_ops
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tf
 from repro.serve import Request, ServeEngine, padded_prompt_len
@@ -400,3 +401,54 @@ def test_pallas_engine_softcap_prefix_sharing():
     # same prefill work the XLA lane did
     assert eng.stats.shared_prefill_hits == xla.stats.shared_prefill_hits
     assert eng.stats.prefill_chunks == xla.stats.prefill_chunks
+
+
+# ---------------------------------------------------------------------------
+# the pool stays one buffer across the layer scan
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("pattern", [("attn",), ("attn", "attn_local", "mamba")],
+                         ids=["dense", "hybrid"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_pool_is_updated_in_place_across_the_layer_scan(program, pattern):
+    """The donated paged programs carry the pool through the layer scan and
+    scatter into it: the optimized module holds no pool-shaped copy,
+    broadcast or dynamic-update-slice (a per-layer slice restacked as scan
+    output, its zero-filled stack, the copy back), only the scatter."""
+    cfg = _cfg(pattern=pattern, num_layers=2 * len(pattern), window=6,
+               ssm_chunk=8)
+    params = tf.init_params(cfg, jax.random.key(0))
+    nb, blk, b, n_max = 37, 8, 4, 5
+    pages = tf.init_pages(cfg, nb, blk)
+    skip = tf.paged_positions(cfg)
+    if program == "decode_step":
+        cache = dict(tf.init_cache(cfg, b, n_max * blk, skip=skip),
+                     len=jnp.zeros((b,), jnp.int32))
+
+        def fn(params, cache, pages, tables, toks):
+            return tf.decode_step(cfg, params, cache, toks, pages=pages,
+                                  tables=tables)
+
+        args = (params, cache, pages, jnp.zeros((b, n_max), jnp.int32),
+                jnp.zeros((b, 1), jnp.int32))
+        donate = (1, 2)
+    else:
+        row = dict(tf.init_cache(cfg, 1, n_max * blk, skip=skip),
+                   len=jnp.zeros((1,), jnp.int32))
+
+        def fn(params, pages, row, toks, ptab, wtab, off):
+            return tf.prefill_chunk(cfg, params, row, pages,
+                                    {"tokens": toks}, off, ptab, wtab)
+
+        args = (params, pages, row, jnp.zeros((1, 2 * blk), jnp.int32),
+                jnp.zeros((2,), jnp.int32), jnp.asarray([3, 4], jnp.int32),
+                jnp.int32(2 * blk))
+        donate = (1, 2)
+    text = jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+    shape = "f32[" + ",".join(map(str, pages["pos0"]["k"].shape)) + "]"
+    ops = pool_ops(text, shape)
+    kinds = {op for _, op in ops}
+    assert kinds <= {"parameter", "get-tuple-element", "scatter"}, ops
+    # K and V of every paged position, written in place
+    assert sum(op == "scatter" for _, op in ops) >= 2 * len(pages), ops

@@ -92,18 +92,22 @@ def test_chunk_attention_compiles(one_chip):
              ((c,), I32), ((sk,), I32), ((sk,), jnp.bool_))
 
 
-def test_paged_decode_compiles(one_chip):
-    slots, blocks, block, n_max = 8, 1024, 128, 24
+@pytest.mark.parametrize("layers", [None, 32], ids=["one_layer", "stacked"])
+def test_paged_decode_compiles(one_chip, layers):
+    # one layer's pool, or the serving pool stacked over all 32 layers and
+    # read at a layer index, as the decode step's layer scan calls it
+    slots, blocks, block, n_max = 8, 1024 if layers is None else 256, 128, 24
+    pool = (blocks, block, KV_HEADS, HD) if layers is None else (
+        layers, blocks, block, KV_HEADS, HD)
 
-    def paged(q, pool_k, pool_v, tables, lengths):
+    def paged(q, pool_k, pool_v, tables, lengths, *layer):
         return kattn.paged_decode_attention(q, pool_k, pool_v, tables, lengths,
-                                            interpret=False)
+                                            *layer, interpret=False)
 
     _compile(paged, one_chip,
-             ((slots, 1, HEADS, HD), BF16),
-             ((blocks, block, KV_HEADS, HD), BF16),
-             ((blocks, block, KV_HEADS, HD), BF16),
-             ((slots, n_max), I32), ((slots,), I32))
+             ((slots, 1, HEADS, HD), BF16), (pool, BF16), (pool, BF16),
+             ((slots, n_max), I32), ((slots,), I32),
+             *([] if layers is None else [((), I32)]))
 
 
 FFN_ACTS = [((1, SEQ, D_MODEL), BF16), ((1, SEQ, D_FF), BF16)]
@@ -132,3 +136,52 @@ def test_psgn_fused_compiles(one_chip):
 def test_quantize_int8_compiles(one_chip):
     _compile(lambda x: quant.quantize_int8(x, interpret=False), one_chip,
              ((D_MODEL, D_FF), F32))
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_paged_programs_update_the_pool_in_place(one_chip, program, monkeypatch):
+    """The donated paged programs on the Pallas lane, compiled for the chip
+    at the serving pool's tile shapes (blocks of 128 rows of 4 KV heads of
+    128): the pool-shaped instructions are the in-place scatters and
+    nothing else.  The chip's compiler is the one that lays a pool out anew
+    around a scatter of whole blocks, which a CPU compile does not show."""
+    import repro.kernels
+    from _hlo import pool_ops
+    from repro.configs.base import ModelConfig
+    from repro.models import transformer as tf
+
+    # the model leaves interpret mode to the lane, which sees the CPU here
+    monkeypatch.setattr(repro.kernels, "default_interpret", lambda: False)
+    cfg = ModelConfig(
+        name="t", family="dense", num_layers=2, d_model=512, num_heads=4,
+        num_kv_heads=KV_HEADS, head_dim=HD, d_ff=1024, vocab_size=512,
+        pattern=("attn",), param_dtype="bfloat16", compute_dtype="bfloat16",
+        remat=False, attn_impl="pallas",
+    )
+
+    def spec(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = spec(jax.eval_shape(lambda: tf.init_params(cfg, jax.random.key(0))))
+    pages = spec(jax.eval_shape(lambda: tf.init_pages(cfg, 64, 128)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, I32, sharding=one_chip)  # noqa: E731
+    if program == "decode_step":
+        def fn(params, cache, pages, tables, toks):
+            return tf.decode_step(cfg, params, cache, toks, pages=pages,
+                                  tables=tables)
+
+        args = (params, {"len": i32(8)}, pages, i32(8, 16), i32(8, 1))
+    else:
+        def fn(params, pages, row, toks, ptab, wtab, off):
+            return tf.prefill_chunk(cfg, params, row, pages,
+                                    {"tokens": toks}, off, ptab, wtab)
+
+        args = (params, pages, {"len": i32(1)}, i32(1, 256), i32(2), i32(2),
+                i32())
+    text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    shape = "bf16[" + ",".join(map(str, pages["pos0"]["k"].shape)) + "]"
+    ops = pool_ops(text, shape)
+    assert {op for _, op in ops} <= {"parameter", "get-tuple-element", "scatter"}, ops
+    assert sum(op == "scatter" for _, op in ops) >= 2, ops
