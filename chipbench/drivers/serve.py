@@ -13,8 +13,10 @@ delivered tokens to it: TTFT is send to the first token, ITL every gap
 between consecutive tokens.  After the window (and the traced stretch of a
 ``--trace 1`` run) the engine is freed and a sample of the requests finished
 in the window, drawn from the seed with the longest among them, is run
-through the plain reference: the compared number is the widest gap by which
-a served token's logit lies below the reference's best at that position.
+through the plain reference the configuration names
+(``harness.load_reference``), which also makes the weights: the compared
+number is the widest gap by which a served token's logit lies below the
+reference's best at that position.
 """
 
 from __future__ import annotations
@@ -27,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench import harness
-from chipbench.reference import llama
 from chipbench.traffic import requests as gen
 
 
@@ -120,11 +121,12 @@ def run(cell: harness.Cell, t_start: float, control: bool = False) -> dict:
     from repro.serve import ServeEngine
 
     c, mix, sp = cell.config, cell.traffic, cell.spec
-    dims = llama.dims_of(c)
+    ref = harness.load_reference(cell)
+    dims = ref.dims_of(c)
     cfg = get_config(c["arch"]).replace(**c["program"])
     eng_kw = c["engine"]
     counter = harness.CompileCounter()
-    weights = llama.make_weights(dims, cell.seed, c["program"]["param_dtype"])
+    weights = ref.make_weights(dims, harness.key_of(cell.seed), c["program"]["param_dtype"])
     spans = Tracer(jax_annotate=True) if cell.trace else None
     origin = time.perf_counter()  # the tracer's ts 0, to within microseconds
     eng = ServeEngine(cfg, weights, max_slots=eng_kw["slots"], max_seq=eng_kw["max_seq"],
@@ -150,7 +152,8 @@ def run(cell: harness.Cell, t_start: float, control: bool = False) -> dict:
     window_compiles = counter.count - compiles0
 
     rec = {"setup_s": t0 - t_start, "window_s": t1 - t0, "window_compiles": window_compiles,
-           "dims": dims, "peak": cell.peak, "chips": len(cell.devices), "block": eng_kw["block"]}
+           "dims": dims, "peak": cell.peak, "chips": len(cell.devices), "block": eng_kw["block"],
+           "counts": harness.counts_name(c)}
     tokens, ttft, itl = 0, [], []
     for rid, ts in loop.times.items():
         prev = None
@@ -187,7 +190,7 @@ def run(cell: harness.Cell, t_start: float, control: bool = False) -> dict:
               for rid in finished}
     del eng, loop
     gc.collect()
-    got = check(cell, dims, served, eng_kw["block"], sp, control=control)
+    got = check(cell, ref, dims, served, eng_kw["block"], sp, control=control)
     rec["checked"] = got
     rec["checks"] = [{"name": "served_token_gap", "limit": cell.limits["served_token_gap"],
                       "value": got["served_token_gap"]}]
@@ -269,24 +272,25 @@ def sequences(served: dict, rids, block: int, span: int):
     return out
 
 
-def check(cell, dims, served, block, sp, *, control: bool = False) -> dict:
-    """Widest gap by which a served token's logit lies below the reference's
-    best (and, with ``control``, the same for the token the float8 control
-    puts first)."""
-    weights = llama.make_weights(dims, cell.seed, cell.config["program"]["param_dtype"])
-    fwd = jax.jit(lambda w, t, prec: llama.logits(dims, w, t[None], prec)[0],
+def check(cell, ref, dims, served, block, sp, *, control: bool = False) -> dict:
+    """Widest gap by which a served token's logit lies below the best of the
+    reference module ``ref`` (and, with ``control``, the same for the token
+    the float8 control puts first)."""
+    weights = ref.make_weights(dims, harness.key_of(cell.seed),
+                               cell.config["program"]["param_dtype"])
+    fwd = jax.jit(lambda w, t, prec: ref.logits(dims, w, t[None], prec)[0],
                   static_argnums=2)
     gap, gap_ctl, n_tok = 0.0, 0.0, 0
     for seq, plen, toks in sequences(served, sample(served, sp["check_requests"], cell.seed),
                                      block, sp["check_span"]):
-        ref = np.asarray(fwd(weights, jnp.asarray(seq), "f32")[plen - 1:plen - 1 + len(toks)])
-        best = ref.max(-1)
-        gap = max(gap, float(np.max(best - ref[np.arange(len(toks)), toks])))
+        want = np.asarray(fwd(weights, jnp.asarray(seq), "f32")[plen - 1:plen - 1 + len(toks)])
+        best = want.max(-1)
+        gap = max(gap, float(np.max(best - want[np.arange(len(toks)), toks])))
         n_tok += len(toks)
         if control:
             low = np.asarray(fwd(weights, jnp.asarray(seq), "fp8")[plen - 1:plen - 1 + len(toks)])
             first = low.argmax(-1)
-            gap_ctl = max(gap_ctl, float(np.max(best - ref[np.arange(len(toks)), first])))
+            gap_ctl = max(gap_ctl, float(np.max(best - want[np.arange(len(toks)), first])))
     out = {"served_token_gap": gap, "checked_tokens": n_tok}
     if control:
         out["control_gap"] = gap_ctl

@@ -11,11 +11,41 @@ A cell is found by its name in ``BENCHMARK.json``; its files are
 
 so a later cell, configuration, traffic mix, driver or metric is a new file
 and an entry in ``BENCHMARK.json``, with no edit here.
+
+A configuration brings its own reference and counts, each a module under
+``chipbench/`` named by its path from the checkout's root:
+
+    "reference"  (required) the plain reference the drivers compare with and
+                 make their weights from.  It provides
+                     dims_of(config) -> dims          the sizes, as a dict
+                     make_weights(dims, key, dtype)   every weight, made on the
+                         device from the PRNG key ``key_of(seed)``, in the
+                         tree layout the system under test loads; the drivers
+                         trace it under ``jit`` with the key an argument, so
+                         one compiled program serves every seed
+                 and, for serving cells,
+                     logits(dims, weights, tokens, precision) -> (B, S, V)
+                 or, for training cells,
+                     loss(dims, weights, tokens, targets, precision) -> mean
+                 in float32, ``precision`` "f32" (``highest`` matmuls) or
+                 "fp8" (the control).  It imports nothing of the program.
+    "counts"     (optional, ``chipbench/flops.py`` when absent) the work the
+                 algorithms need, from ``dims`` alone, for the readers that
+                 count it: train_flops_per_token(dims, seq_len) for
+                 ``train_mfu``; flash_call(dims, kind, rows, seq_len) for
+                 ``flash_roofline.train``; decode_step(dims, contexts) and
+                 prefill_chunk(dims, chunk, prior) for ``serve_mfu``;
+                 paged_decode_call(dims, contexts, block) for
+                 ``paged_decode_roofline.serve``.  The first returns
+                 operations, the others (operations, bytes).  A driver puts
+                 the module's path in its record under ``counts``, and the
+                 readers load it from there (``counts_of``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -28,6 +58,14 @@ BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".chipbench_trace"
+
+
+def key_of(seed: int):
+    """A PRNG key from any non-negative seed, also those past 32 bits."""
+    import jax
+
+    seed = int(seed)
+    return jax.random.fold_in(jax.random.key(seed % (2**31)), seed // (2**31))
 
 
 class NoDevice(RuntimeError):
@@ -88,6 +126,34 @@ def load_cell(name: str, manifest_path: Path | None = None) -> Cell:
         end_to_end=e2e,
         per_layer=per_layer,
     )
+
+
+DEFAULT_COUNTS = "chipbench/flops.py"
+
+
+@functools.lru_cache(maxsize=None)
+def bench_module(rel: str):
+    """The module at ``rel``, a path from the checkout's root that has to lie
+    under ``chipbench/``; loaded once per process."""
+    path = (ROOT / rel).resolve()
+    if not path.is_relative_to(BENCH) or path.suffix != ".py":
+        raise ValueError(f"{rel!r} is not a module under {BENCH.name}/")
+    return _load_module(path, "chipbench_" + "_".join(path.relative_to(BENCH).with_suffix("").parts))
+
+
+def load_reference(cell: Cell):
+    """The plain reference the cell's configuration names."""
+    return bench_module(cell.config["reference"])
+
+
+def counts_name(config: dict) -> str:
+    """Path of the counts module a configuration names (or the default)."""
+    return config.get("counts", DEFAULT_COUNTS)
+
+
+def counts_of(rec: dict):
+    """The counts module a driver's record names."""
+    return bench_module(rec.get("counts", DEFAULT_COUNTS))
 
 
 def load_driver(kind: str):
@@ -169,7 +235,8 @@ def profiled(cell: Cell):
     finally:
         jax.profiler.stop_trace()
     out.update(reduce_dir(path, window_name="chipbench.window",
-                          kernels=cell.spec.get("kernels", [])))
+                          kernels=cell.spec.get("kernels", {}),
+                          collectives=cell.spec.get("collectives")))
     shutil.rmtree(path, ignore_errors=True)
 
 

@@ -31,12 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def key_of(seed: int):
-    """A PRNG key from any non-negative seed, also those past 32 bits."""
-    seed = int(seed)
-    return jax.random.fold_in(jax.random.key(seed % (2**31)), seed // (2**31))
-
-
 def dims_of(config: dict) -> dict:
     """The sizes this module uses, from a configuration file's published
     keys (Hugging Face ``config.json`` names)."""
@@ -103,10 +97,10 @@ def _init_jit(dims_items: tuple, dtype: str, readout: float):
     return jax.jit(init)
 
 
-def make_weights(dims: dict, seed: int, dtype: str):
-    """All weights, made on the device in one jitted call from ``seed``."""
+def make_weights(dims: dict, key, dtype: str):
+    """All weights, made on the device in one jitted call from ``key``."""
     items = tuple(sorted((k, v) for k, v in dims.items() if isinstance(v, (int, float))))
-    return _init_jit(items, dtype, float(dims.get("readout_scale", 1.0)))(key_of(seed))
+    return _init_jit(items, dtype, float(dims.get("readout_scale", 1.0)))(key)
 
 
 # ---------------------------------------------------------------------------

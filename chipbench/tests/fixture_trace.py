@@ -20,6 +20,7 @@ def stand_in() -> None:
         out: dict = {}
         yield out
         out.update(trace_reduce.reduce_dir(str(PATH), window_name="chipbench.window",
-                                           kernels=cell.spec.get("kernels", {})))
+                                           kernels=cell.spec.get("kernels", {}),
+                                           collectives=cell.spec.get("collectives")))
 
     harness.profiled = profiled

@@ -73,10 +73,11 @@ def scratch_copy(dest: Path) -> Path:
 
 def rehearse(cell: str, *, seconds: float = 2.0, trace: int = 0, fault: str = "",
              devices: int = 1, dest: Path | None = None, seed: int = 3_000_000_017,
-             calibrate: str = ""):
+             calibrate: str = "", args=()):
     """Run the cell in a scratch copy; returns (exit code, last stdout line
     as a dict or None, stderr).  ``calibrate="<seeds>;<control seeds>"`` runs
-    chipbench/calibrate.py instead, and the whole stdout comes back."""
+    chipbench/calibrate.py instead, with ``args`` added to its arguments,
+    and the whole stdout comes back."""
     own = dest is None
     dest = Path(tempfile.mkdtemp()) if own else dest
     try:
@@ -87,7 +88,7 @@ def rehearse(cell: str, *, seconds: float = 2.0, trace: int = 0, fault: str = ""
         if calibrate:
             seeds, controls = calibrate.split(";")
             argv = ["--workload", cell, "--seeds", seeds, "--control-seeds", controls,
-                    "--seconds", str(seconds)]
+                    "--seconds", str(seconds), *args]
         code = CHILD.format(root=str(dest), src=str(ROOT / "src"), fault=fault, argv=argv,
                             trace=trace, calibrate=bool(calibrate))
         env = dict(os.environ, JAX_PLATFORMS="cpu",

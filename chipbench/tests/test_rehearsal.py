@@ -1,9 +1,10 @@
 """Every cell end to end on the CPU at the small test widths (interpret-mode
-Pallas, the look for a chip skipped), the shape of the last line, a cell
-found by file name alone, and the refusals: no TPU, or no program beside
-the benchmark."""
+Pallas, the look for a chip skipped, a four-chip cell on four host
+devices), the shape of the last line, a cell found by file name alone, and
+the refusals: no TPU, or no program beside the benchmark."""
 
 import json
+import re
 import shutil
 import subprocess
 import sys
@@ -15,7 +16,8 @@ from chipbench.tests import rehearse
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
-CELLS = [w["name"] for w in MANIFEST["workloads"] if w["chips"] == 1]
+CHIPS = {w["name"]: w["chips"] for w in MANIFEST["workloads"]}
+CELLS = list(CHIPS)
 
 
 def e2e_names(cell):
@@ -29,7 +31,7 @@ def layer_names(cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_runs_and_prints_its_metrics(cell):
-    rc, last, err = rehearse.rehearse(cell, seconds=2)
+    rc, last, err = rehearse.rehearse(cell, seconds=2, devices=CHIPS[cell])
     assert rc == 0, err[-3000:]
     assert set(last) == {"correct", "attempted", "failed", "metrics", "device", "checks"}
     assert list(last)[-1] == "checks"
@@ -39,15 +41,27 @@ def test_cell_runs_and_prints_its_metrics(cell):
     assert "check window_compiles: 0" in err
 
 
-@pytest.mark.parametrize("cell", ["yi6b-train-divebatch", "yi6b-serve-rag"])
+@pytest.mark.parametrize("cell", ["yi6b-train-divebatch", "yi6b-serve-rag", "yi6b-train-fsdp4"])
 def test_traced_run_reports_per_layer_metrics(cell):
-    rc, last, err = rehearse.rehearse(cell, seconds=2, trace=1)
+    rc, last, err = rehearse.rehearse(cell, seconds=2, trace=1, devices=CHIPS[cell])
     assert rc == 0, err[-3000:]
     assert set(last["metrics"]) <= layer_names(cell)
     assert last["metrics"], last
     assert last["device"]["busy_s"] > 0 and last["device"]["window_s"] > 0
     assert len(last["breakdown"]["device_ops"]) <= 10
     assert len(last["breakdown"]["idle_gaps"]) <= 10
+
+
+def test_four_chip_cell_trains_sharded_over_four_devices():
+    """dp = fsdp = 4: correct, nothing compiled in the window, and the
+    schedule 4x4 8x4 16x4 then 32 sequences."""
+    rc, last, err = rehearse.rehearse("yi6b-train-fsdp4", seconds=3, devices=4)
+    assert rc == 0, err[-3000:]
+    assert last["correct"] is True, last["checks"]
+    assert last["device"]["count"] == 4
+    assert last["checks"]["window_compiles"]["value"] == 0
+    schedule = re.search(r"^schedule (.*)$", err, re.M).group(1)
+    assert re.fullmatch(r"4x4 8x4 16x4 32x\d+", schedule), schedule
 
 
 def test_a_dropped_in_cell_file_is_picked_up(tmp_path):

@@ -98,3 +98,50 @@ def test_recorded_v5e_trace():
     assert [g[0] for g in r["idle_gaps"][:3]] == ["chipbench.host_wait"] * 3
     assert all(g[1] > 0.009 for g in r["idle_gaps"][:3])
     assert r["idle_gaps"][3][0] == "chipbench.step"
+
+
+def planes_with_async(device_events, async_events, host_events):
+    host = NS(name="/host:CPU", lines=[NS(name="python", events=host_events)])
+    dev = NS(name="/device:TPU:0", lines=[NS(name="XLA Ops", events=device_events),
+                                         NS(name="Async XLA Ops", events=async_events)])
+    return [host, dev]
+
+
+FSDP4 = json.loads((WORKLOADS / "yi6b-train-fsdp4.json").read_text())["collectives"]
+
+
+@pytest.mark.parametrize("device,async_ops,share,total", [
+    # a collective wholly under a fusion is hidden
+    ([ev("%all-gather.1 = bf16[8] all-gather(bf16[2] %p)", 10, 30),
+      ev("%fusion.2 = bf16[8] fusion(bf16[8] %q), kind=kLoop", 0, 50)], [], 0.0, 30.0),
+    # a bare collective reads its share of the window
+    ([ev("%collective-permute-done.3 = bf16[8] collective-permute-done(bf16[8] %s)", 20, 30),
+      ev("%fusion.2 = bf16[8] fusion(bf16[8] %q), kind=kLoop", 60, 10)], [], 30.0, 30.0),
+    # a loop around it hides nothing; an operation that reads a collective's
+    # output is no collective; an async span in flight counts as collective
+    # time only
+    ([ev("%while.1 = (f32[]) while(f32[] %p)", 0, 100),
+      ev("%all-reduce.5 = f32[8] all-reduce(f32[8] %g), to_apply=%add", 10, 10),
+      ev("%fusion.7 = f32[8] fusion(f32[8] %all-reduce.5), kind=kLoop", 20, 10)],
+     [ev("%collective-permute-start.4 = (bf16[8]) collective-permute-start(bf16[8] %w)", 40, 20)],
+     10.0, 30.0),
+])
+def test_exposed_collective_share(device, async_ops, share, total):
+    from chipbench import harness
+
+    host = [ev("chipbench.window", 0, 100), ev("step", 0, 100)]
+    tr = trace_reduce.reduce_planes(planes_with_async(device, async_ops, host), collectives=FSDP4)
+    assert tr["collective_s"] == pytest.approx(total / 1000)
+    rec = {"steps": 1, "trace": tr}
+    assert harness.load_metric("exposed_collective_share.train").read(rec) == pytest.approx(share)
+    assert harness.load_metric("collective_share.train").read(rec) == pytest.approx(total)
+
+
+def test_no_collective_reads_nothing():
+    from chipbench import harness
+
+    host = [ev("chipbench.window", 0, 100)]
+    tr = trace_reduce.reduce_planes(planes([ev("fusion.1", 0, 50)], host), collectives=FSDP4)
+    assert tr["collective_s"] == 0
+    for name in ("exposed_collective_share.train", "collective_share.train"):
+        assert harness.load_metric(name).read({"steps": 1, "trace": tr}) is None

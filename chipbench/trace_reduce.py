@@ -3,7 +3,9 @@
 The window is the host span named ``window_name`` (``chipbench.window``,
 opened by the harness around the traced stretch).  On each device plane
 (``/device:TPU:<n>``) the events of the ``XLA Ops`` line are the operations
-that ran on the chip.  From them, clipped to the window:
+that ran on the chip; the ``Async XLA Ops`` line holds the spans of
+asynchronous operations, of which only collectives are read.  From them,
+clipped to the window:
 
   busy_s               union of the operation intervals, averaged over devices
   window_s             length of the window
@@ -19,8 +21,14 @@ that ran on the chip.  From them, clipped to the window:
                        trace names a Pallas call after the jitted function
                        that holds it, not after its kernel, so the pattern
                        matches the call's output types
-  exposed_collective_s time in which a collective runs and no other
-                       operation does, averaged over devices
+  collective_s         time in which a collective runs on either line,
+                       averaged over devices.  A collective is an operation
+                       whose HLO text matches the ``collectives`` pattern
+                       (the workload file's; by default the opcodes of
+                       COLLECTIVES)
+  exposed_collective_s time in which a collective runs on the ``XLA Ops``
+                       line and no other operation (loops and calls left
+                       out) does, averaged over devices
   idle_gaps            [label, seconds] of the longest gaps (over 1 us)
                        between device operations (on any device), labelled
                        with the innermost host span open at the gap's middle
@@ -35,8 +43,10 @@ import re
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "collective-permute",
                "all-to-all", "allgather", "allreduce", "reducescatter")
+_DEFAULT_COLLECTIVE = "|".join(COLLECTIVES)
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+ASYNC_LINE = "Async XLA Ops"
 SKEW_NS = 10e6  # device events this far before the window may be skewed into it
 MIN_GAP_NS = 1e3  # shorter idle gaps are not listed
 # ops whose time is that of the ops they contain (a scan's while loop)
@@ -109,15 +119,17 @@ def _window(planes, window_name: str):
 
 
 def reduce_planes(planes, *, window_name: str = "chipbench.window", kernels=None,
-                  n_gaps: int = 10, n_ops: int = 10) -> dict:
+                  collectives: str | None = None, n_gaps: int = 10, n_ops: int = 10) -> dict:
     planes = [(p.name, [(line.name, list(line.events)) for line in p.lines]) for p in planes]
     kernels = {k: re.compile(v) for k, v in (kernels or {}).items()}
+    is_collective = (re.compile(collectives).search if collectives else
+                     lambda text: re.search(_DEFAULT_COLLECTIVE, _op_name(text).lower()))
     w0, w1, host_events = _window(planes, window_name)
     host = sorted(((float(e.start_ns), float(e.start_ns + e.duration_ns), e.name)
                    for e in host_events
                    if e.name != window_name and w0 <= e.start_ns <= w1),
                   key=lambda t: t[0])
-    devices = [dict(lines)[OPS_LINE] for name, lines in planes
+    devices = [(dict(lines)[OPS_LINE], dict(lines).get(ASYNC_LINE, [])) for name, lines in planes
                if name.startswith(DEVICE_PREFIX) and OPS_LINE in dict(lines)]
     if not devices:
         raise ValueError("no device plane with an 'XLA Ops' line in the trace")
@@ -125,22 +137,24 @@ def reduce_planes(planes, *, window_name: str = "chipbench.window", kernels=None
     # traces.  Nothing runs on the chip before the window's first host span
     # dispatches it, so the first operation after the window opens is moved
     # to that span's start, and every device event with it.
-    first = min((float(e.start_ns) for events in devices for e in events
+    first = min((float(e.start_ns) for events, _ in devices for e in events
                  if e.start_ns >= w0 - SKEW_NS), default=w0)
     shift = max(0.0, (host[0][0] if host else w0) - first)
-    busy, exposed, all_merged = 0.0, 0.0, []
+    busy, coll_s, exposed, all_merged = 0.0, 0.0, 0.0, []
     op_s: dict[str, float] = {}
     k_s = {k: 0.0 for k in kernels}
     k_n = {k: 0 for k in kernels}
-    for events in devices:
-        ops, coll = [], []
+    def clipped(events):
         for ev in events:
             s = float(ev.start_ns) + shift
             e = s + float(ev.duration_ns)
             s, e = max(s, w0), min(e, w1)
-            if e <= s:
-                continue
-            name = ev.name
+            if e > s:
+                yield ev.name, s, e
+
+    for events, async_events in devices:
+        ops, coll, other = [], [], []
+        for name, s, e in clipped(events):
             key = _op_name(name)
             if not key.endswith(CONTAINERS):
                 op_s[key] = op_s.get(key, 0.0) + (e - s)
@@ -148,12 +162,18 @@ def reduce_planes(planes, *, window_name: str = "chipbench.window", kernels=None
                 if pattern.search(name):
                     k_s[k] += e - s
                     k_n[k] += 1
-            low = key.lower()
-            (coll if any(c in low for c in COLLECTIVES) else ops).append((s, e))
+            if is_collective(name):
+                coll.append((s, e))
+            else:
+                ops.append((s, e))
+                if not key.endswith(CONTAINERS):
+                    other.append((s, e))
         merged = _union(ops + coll)
         all_merged.extend(merged)
         busy += _length(merged)
-        exposed += _subtract(_union(coll), _union(ops))
+        in_flight = [(s, e) for name, s, e in clipped(async_events) if is_collective(name)]
+        coll_s += _length(_union(coll + in_flight))
+        exposed += _subtract(_union(coll), _union(other))
     n = len(devices)
     # gaps in which no device ran anything
     gaps, cur = [], w0
@@ -181,6 +201,7 @@ def reduce_planes(planes, *, window_name: str = "chipbench.window", kernels=None
         "devices": n,
         "device_ops": [[k, v / n / 1e9] for k, v in ops_sorted[:n_ops]],
         "kernels": {k: {"seconds": k_s[k] / n / 1e9, "calls": k_n[k] / n} for k in kernels},
+        "collective_s": coll_s / n / 1e9,
         "exposed_collective_s": exposed / n / 1e9,
         "idle_gaps": [[label((s + e) / 2), (e - s) / 1e9] for s, e in gaps[:n_gaps]],
     }
