@@ -27,6 +27,7 @@ _MODULES = {
     "llama3-405b": "llama3_405b",
     "hubert-xlarge": "hubert_xlarge",
     "jamba-v0.1-52b": "jamba_v01_52b",
+    "lfm2-8b-a1b": "lfm2_8b_a1b",
 }
 
 ARCH_NAMES = tuple(_MODULES)

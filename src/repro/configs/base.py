@@ -31,8 +31,9 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0  # 0 => d_model // num_heads
 
-    # layer pattern (repeating period; num_layers % len(pattern) == 0)
-    pattern: tuple[str, ...] = ("attn",)  # 'attn' | 'attn_local' | 'mamba'
+    # layer pattern (repeating period; num_layers % len(pattern) == 0, or
+    # num_layers < len(pattern): the first num_layers layers of one period)
+    pattern: tuple[str, ...] = ("attn",)  # 'attn' | 'attn_local' | 'mamba' | 'conv'
     ffn_pattern: tuple[str, ...] = ("dense",)  # 'dense' | 'moe'
 
     # attention details
@@ -42,12 +43,22 @@ class ModelConfig:
     window: int | None = None  # sliding window for 'attn_local'
     rope_theta: float = 10_000.0
     causal: bool = True  # False => encoder-only (no decode shapes)
+    qk_norm: bool = False  # RMSNorm over each head of q and k before RoPE
 
     # moe
     num_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
     moe_impl: str = "gspmd"  # 'gspmd' | 'ep' (shard_map explicit all-to-all)
+    # 'softmax': capacity dispatch over every expert, Switch aux loss;
+    # 'sigmoid': sigmoid scores, a non-trained expert bias that only selects,
+    # dropless grouped matmuls over the experts this layer holds
+    router: str = "softmax"
+    d_ff_expert: int = 0  # expert FFN width; 0 => d_ff
+    experts_held: int = 0  # experts [0, held) a 'sigmoid' layer holds; 0 => num_experts
+
+    # gated short convolution ('conv' mixer)
+    conv_kernel: int = 3
 
     # ssm
     ssm_state: int = 16
@@ -59,6 +70,8 @@ class ModelConfig:
     # io
     input_mode: str = "tokens"  # 'tokens' | 'embeddings' (audio/vlm stub frontend)
     norm_type: str = "rms"  # 'rms' | 'layer'
+    norm_eps: float = 1e-6
+    tie_embeddings: bool = False  # the output head is the embedding's transpose
     ffn_act: str = "silu"  # activation inside (GLU-style) FFN
     ffn_glu: bool = True  # gated FFN (SwiGLU); False => plain 2-layer MLP
 
@@ -78,16 +91,38 @@ class ModelConfig:
     source: str = ""
 
     def __post_init__(self):
+        # JSON configurations give lists; the config stays hashable
+        for name in ("pattern", "ffn_pattern"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.num_layers < len(self.pattern):
+            if len(self.ffn_pattern) == len(self.pattern):
+                object.__setattr__(self, "ffn_pattern", self.ffn_pattern[: self.num_layers])
+            object.__setattr__(self, "pattern", self.pattern[: self.num_layers])
         if self.num_layers % len(self.pattern) != 0:
             raise ValueError(f"{self.name}: num_layers % pattern period != 0")
         if len(self.ffn_pattern) not in (1, len(self.pattern)):
             # allow ffn_pattern either scalar-like or same period
             if len(self.pattern) % len(self.ffn_pattern) != 0:
                 raise ValueError(f"{self.name}: ffn_pattern period mismatch")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"{self.name}: unknown router {self.router!r}")
+        if self.experts_held and self.router != "sigmoid":
+            raise ValueError(f"{self.name}: only the sigmoid router holds a share of the experts")
+        if self.held_experts > self.num_experts:
+            raise ValueError(f"{self.name}: {self.held_experts} held experts of "
+                             f"{self.num_experts}")
 
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.num_heads if self.num_heads else 0)
+
+    @property
+    def expert_width(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def period(self) -> int:
@@ -132,7 +167,12 @@ ENCODER_ONLY_ARCHS = {"hubert-xlarge"}
 
 
 def cell_supported(arch_name: str, shape_name: str, causal: bool) -> tuple[bool, str]:
+    from repro.configs import get_config
+
     shape = SHAPES[shape_name]
+    # short-convolution layers train only (models.transformer.refuse_serving)
+    if "conv" in get_config(arch_name).pattern and shape.kind != "train":
+        return False, "short-convolution state has no serving cache yet (ROADMAP Reach 3)"
     if arch_name in ENCODER_ONLY_ARCHS and shape.kind == "decode":
         return False, "encoder-only: no decode step"
     if shape.name == "long_500k" and arch_name in FULL_ATTENTION_ARCHS:

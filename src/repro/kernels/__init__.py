@@ -13,6 +13,9 @@ Modules
                 cross-layer sum accumulated in VMEM.
   quant.py      fused rowwise int8 quantisation for cross-pod grad
                 compression.
+  gmm.py        grouped matrix products for the dropless expert layer:
+                the megablox gmm/tgmm kernels under a custom VJP on TPU,
+                ``jax.lax.ragged_dot`` elsewhere.
   ops.py        jit wrappers + dispatch: ``choose_method`` picks the psgn
                 factorisation by FLOP count, ``persample_sq_norm_tree``
                 groups same-shape layers into the fused kernel.
@@ -49,7 +52,7 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 # submodules import the two functions above from this package
-from repro.kernels import attention, ops, psgn, quant, ref  # noqa: E402
+from repro.kernels import attention, gmm, ops, psgn, quant, ref  # noqa: E402
 
-__all__ = ["attention", "default_interpret", "ops", "psgn", "quant", "ref",
+__all__ = ["attention", "default_interpret", "gmm", "ops", "psgn", "quant", "ref",
            "resolve_interpret"]

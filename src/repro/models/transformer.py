@@ -1,7 +1,8 @@
-"""The composable decoder/encoder stack covering all 10 assigned archs.
+"""The composable decoder/encoder stack covering every configured arch.
 
 A model is (pattern x repeats) blocks; each pattern position has a mixer
-('attn' | 'attn_local' | 'mamba') and an FFN kind ('dense' | 'moe' | 'none').
+('attn' | 'attn_local' | 'mamba' | 'conv') and an FFN kind ('dense' | 'moe'
+| 'none').
 Parameters for each pattern position are STACKED over the repeat axis R and
 the stack runs as one ``lax.scan`` (+ optional ``jax.checkpoint``) — compile
 time and HLO size are O(period), not O(num_layers), which is what makes the
@@ -33,6 +34,7 @@ from repro.dist.plan import constrain, current_plan, maps_shards, shard_local
 from repro.dist.sharding import cache_pspecs, shardings_of
 from repro.kernels import attention as kernels_attn
 from repro.models import attention as attn_lib
+from repro.models import conv as conv_lib
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.layers import (
@@ -51,7 +53,33 @@ PyTree = Any
 
 
 def _norm(cfg: ModelConfig, params, x):
-    return rms_norm(params, x) if cfg.norm_type == "rms" else layer_norm(params, x)
+    if cfg.norm_type == "rms":
+        return rms_norm(params, x, cfg.norm_eps)
+    return layer_norm(params, x, cfg.norm_eps)
+
+
+def _head(cfg: ModelConfig, params: PyTree) -> jax.Array:
+    """The output head's (d_model, vocab) kernel."""
+    if cfg.tie_embeddings:
+        return params["embed"]["embedding"].T
+    return params["lm_head"]["kernel"]
+
+
+def step_counters(cfg: ModelConfig) -> tuple[str, ...]:
+    """The step counters the model's layers report beside ``moe_aux``."""
+    return moe_lib.COUNTERS if cfg.router == "sigmoid" and "moe" in cfg.ffn_pattern else ()
+
+
+def _zero_stats(cfg: ModelConfig) -> dict:
+    stats = {"moe_aux": jnp.zeros((), jnp.float32)}
+    stats.update({k: jnp.zeros((), jnp.int32) for k in step_counters(cfg)})
+    return stats
+
+
+def _fold_stats(acc: dict, new: dict) -> dict:
+    """Layer statistics summed over layers, the ``max`` ones maxed."""
+    return {k: (jnp.maximum(v, new[k]) if "max" in k else v + new[k]) if k in new else v
+            for k, v in acc.items()}
 
 
 # Plan roles of each dim for the Pallas kernels (dist.plan.shard_local):
@@ -90,18 +118,25 @@ def _init_attn(cfg: ModelConfig, key) -> dict:
     hd = cfg.resolved_head_dim
     k1, k2, k3, k4 = jax.random.split(key, 4)
     dt = _pdtype(cfg)
-    return {
+    p = {
         "q": dense_init(k1, cfg.d_model, cfg.num_heads * hd, dt, use_bias=cfg.qkv_bias),
         "k": dense_init(k2, cfg.d_model, cfg.num_kv_heads * hd, dt, use_bias=cfg.qkv_bias),
         "v": dense_init(k3, cfg.d_model, cfg.num_kv_heads * hd, dt, use_bias=cfg.qkv_bias),
         "o": dense_init(k4, cfg.num_heads * hd, cfg.d_model, dt),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = norm_init(hd, dt)
+        p["k_norm"] = norm_init(hd, dt)
+    return p
 
 
 def _init_ffn(cfg: ModelConfig, key, kind: str) -> dict:
     dt = _pdtype(cfg)
+    if kind == "moe" and cfg.router == "sigmoid":
+        return moe_lib.held_moe_init(key, cfg.d_model, cfg.expert_width, cfg.num_experts,
+                                     cfg.held_experts, dt)
     if kind == "moe":
-        return moe_lib.moe_init(key, cfg.d_model, cfg.d_ff, cfg.num_experts, dt)
+        return moe_lib.moe_init(key, cfg.d_model, cfg.expert_width, cfg.num_experts, dt)
     k1, k2, k3 = jax.random.split(key, 3)
     if cfg.ffn_glu:
         return {
@@ -128,6 +163,8 @@ def _init_block(cfg: ModelConfig, key, pos: int) -> dict:
             k_mix, cfg.d_model, cfg.ssm_state, cfg.ssm_expand, cfg.ssm_conv,
             cfg.ssm_dt_rank, dt,
         )
+    elif kind == "conv":
+        block["conv"] = conv_lib.short_conv_init(k_mix, cfg.d_model, cfg.conv_kernel, dt)
     else:
         raise ValueError(f"unknown mixer kind {kind!r}")
     if ffn_kind != "none":
@@ -148,7 +185,8 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
         stack_keys = jax.random.split(keys[p], cfg.repeats)
         params[f"pos{p}"] = jax.vmap(lambda k: _init_block(cfg, k, p))(stack_keys)
     params["final_norm"] = norm_init(cfg.d_model, dt)
-    params["lm_head"] = dense_init(keys[-2], cfg.d_model, cfg.vocab_size, dt)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense_init(keys[-2], cfg.d_model, cfg.vocab_size, dt)
     return params
 
 
@@ -161,15 +199,27 @@ def _blocks(params: PyTree, cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _attn_sublayer(cfg: ModelConfig, p: dict, x: jax.Array, kind: str,
-                   positions: jax.Array) -> jax.Array:
+def _qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array):
+    """Queries and keys (RMS-normed per head with ``qk_norm``, then rotated)
+    and values of ``x`` (B, S, d) at ``positions``."""
     b, s, _ = x.shape
     hd = cfg.resolved_head_dim
     q = dense(p["q"], x).reshape(b, s, cfg.num_heads, hd)
     k = dense(p["k"], x).reshape(b, s, cfg.num_kv_heads, hd)
     v = dense(p["v"], x).reshape(b, s, cfg.num_kv_heads, hd)
+    if cfg.qk_norm:
+        q = rms_norm(p["q_norm"], q, cfg.norm_eps)
+        k = rms_norm(p["k_norm"], k, cfg.norm_eps)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _attn_sublayer(cfg: ModelConfig, p: dict, x: jax.Array, kind: str,
+                   positions: jax.Array) -> jax.Array:
+    b, s, _ = x.shape
+    hd = cfg.resolved_head_dim
+    q, k, v = _qkv(cfg, p, x, positions)
     window = cfg.window if kind == "attn_local" else None
     impl = attn_lib.resolve_impl(cfg, s)
     if impl == "pallas":
@@ -187,48 +237,55 @@ def _attn_sublayer(cfg: ModelConfig, p: dict, x: jax.Array, kind: str,
 
 
 def _ffn_sublayer(cfg: ModelConfig, p: dict, x: jax.Array, kind: str,
-                  moe_groups: int) -> tuple[jax.Array, jax.Array]:
+                  moe_groups: int) -> tuple[jax.Array, dict]:
+    """The FFN's output and its layer statistics (``moe_aux``, or the
+    ``step_counters`` of a held-share expert layer; none of a dense one)."""
     act = ACTIVATIONS[cfg.ffn_act]
+    if kind == "moe" and cfg.router == "sigmoid":
+        return moe_lib.held_moe_apply(p, x, top_k=cfg.top_k, act=cfg.ffn_act)
     if kind == "moe":
-        from repro.dist.plan import current_plan
-
         plan = current_plan()
         if cfg.moe_impl == "ep" and plan is not None:
             from repro.models.moe_ep import moe_apply_ep
 
-            return moe_apply_ep(
+            y, aux = moe_apply_ep(
                 p, x, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
                 act=cfg.ffn_act, mesh=plan.mesh, dp_axes=plan.dp,
                 ep_axes=plan.ep, tp_axis=plan.tp,
             )
-        return moe_lib.moe_apply(
-            p, x, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-            groups=moe_groups, act=cfg.ffn_act,
-        )
+        else:
+            y, aux = moe_lib.moe_apply(
+                p, x, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+                groups=moe_groups, act=cfg.ffn_act,
+            )
+        return y, {"moe_aux": aux}
     if cfg.ffn_glu:
         h = act(dense(p["w_gate"], x)) * dense(p["w_up"], x)
     else:
         h = act(dense(p["w_in"], x))
-    return dense(p["w_out"], h), jnp.zeros((), jnp.float32)
+    return dense(p["w_out"], h), {}
 
 
 def _block_apply(cfg: ModelConfig, pos: int, p: dict, x: jax.Array,
-                 positions: jax.Array, moe_groups: int) -> tuple[jax.Array, jax.Array]:
+                 positions: jax.Array, moe_groups: int) -> tuple[jax.Array, dict]:
     kind = cfg.pattern[pos]
     h = _norm(cfg, p["norm"], x)
     if kind == "mamba":
         h = ssm_lib.mamba_apply(
             p["mamba"], h, d_state=cfg.ssm_state, dt_rank=cfg.dt_rank, chunk=cfg.ssm_chunk
         )
+    elif kind == "conv":
+        h = conv_lib.short_conv_apply(p["conv"], h)
     else:
         h = _attn_sublayer(cfg, p["attn"], h, kind, positions)
     x = x + h
-    aux = jnp.zeros((), jnp.float32)
+    stats = _zero_stats(cfg)
     if "ffn" in p:
         h = _norm(cfg, p["ffn_norm"], x)
-        h, aux = _ffn_sublayer(cfg, p["ffn"], x=h, kind=cfg.ffn_kind(pos), moe_groups=moe_groups)
+        h, new = _ffn_sublayer(cfg, p["ffn"], x=h, kind=cfg.ffn_kind(pos), moe_groups=moe_groups)
+        stats.update(new)
         x = x + h
-    return x, aux
+    return x, stats
 
 
 # ---------------------------------------------------------------------------
@@ -237,40 +294,45 @@ def _block_apply(cfg: ModelConfig, pos: int, p: dict, x: jax.Array,
 
 
 def _run_stack(cfg: ModelConfig, params: PyTree, x: jax.Array,
-               positions: jax.Array, moe_groups: int) -> tuple[jax.Array, jax.Array]:
+               positions: jax.Array, moe_groups: int) -> tuple[jax.Array, dict]:
     blocks = _blocks(params, cfg)
 
     # For multi-position patterns (gemma2 period 2, jamba period 8) remat each
     # BLOCK, not just the scan body: otherwise the backward of one scan step
     # holds `period` layers of intermediates live at once (measured 47 GiB on
-    # jamba train_4k vs ~12 GiB with per-block remat).
+    # jamba train_4k vs ~12 GiB with per-block remat).  A period that runs
+    # once (lfm2's six layers, unrolled in one scan step) prevents CSE in each
+    # block's checkpoint and takes none around the body: the TPU compile
+    # otherwise held four expert layers' buffers live together.
+    once = cfg.period > 1 and cfg.repeats == 1
+
     def apply_block(p, layer_p, h):
         if cfg.remat and cfg.period > 1:
             return jax.checkpoint(
                 lambda lp, hh: _block_apply(cfg, p, lp, hh, positions, moe_groups),
-                prevent_cse=False,
+                prevent_cse=once,
             )(layer_p, h)
         return _block_apply(cfg, p, layer_p, h, positions, moe_groups)
 
     def body(carry, layer):
-        h, aux = carry
+        h, stats = carry
         h = constrain(h, "residual")
         for p in range(cfg.period):
-            h, a = apply_block(p, layer[f"pos{p}"], h)
-            aux = aux + a
-        return (h, aux), None
+            h, new = apply_block(p, layer[f"pos{p}"], h)
+            stats = _fold_stats(stats, new)
+        return (h, stats), None
 
-    if cfg.remat:
+    if cfg.remat and not once:
         body = jax.checkpoint(body, prevent_cse=False)
 
     if cfg.scan_layers:
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), blocks)
+        (x, stats), _ = jax.lax.scan(body, (x, _zero_stats(cfg)), blocks)
     else:
-        aux = jnp.zeros((), jnp.float32)
+        stats = _zero_stats(cfg)
         for r in range(cfg.repeats):
             layer = jax.tree.map(lambda leaf: leaf[r], blocks)
-            (x, aux), _ = body((x, aux), layer)
-    return x, aux
+            (x, stats), _ = body((x, stats), layer)
+    return x, stats
 
 
 def _embed_input(cfg: ModelConfig, params: PyTree, batch: dict) -> jax.Array:
@@ -365,14 +427,15 @@ def loss_fn(cfg: ModelConfig, params: PyTree, batch: dict,
             moe_groups: int = 1) -> tuple[jax.Array, dict]:
     x = _embed_input(cfg, params, batch)
     positions = jnp.arange(x.shape[1])[None, :]
-    x, aux = _run_stack(cfg, params, x, positions, moe_groups)
+    x, stats = _run_stack(cfg, params, x, positions, moe_groups)
     x = _norm(cfg, params["final_norm"], x)
     loss = xent_chunked(
-        x, params["lm_head"]["kernel"], batch["targets"], cfg.xent_chunk, cfg.final_softcap
+        x, _head(cfg, params), batch["targets"], cfg.xent_chunk, cfg.final_softcap
     )
-    metrics = {"ce_loss": loss, "moe_aux": aux}
-    if cfg.num_experts:
-        loss = loss + 0.01 * aux
+    metrics = {"ce_loss": loss, **stats}
+    # the Switch balance term; the sigmoid router balances by its bias
+    if cfg.num_experts and cfg.router == "softmax":
+        loss = loss + 0.01 * stats["moe_aux"]
     return loss, metrics
 
 
@@ -387,12 +450,21 @@ def _cache_len_for(cfg: ModelConfig, pos: int, seq_len: int) -> int:
     return seq_len
 
 
+def refuse_serving(cfg: ModelConfig) -> None:
+    """Serving keeps no short-convolution state: a 'conv' layer trains only."""
+    if "conv" in cfg.pattern:
+        raise NotImplementedError(
+            f"{cfg.name}: 'conv' (short convolution) layers cannot be served yet: the "
+            "serving cache has no place for their convolution state (ROADMAP Reach 3)")
+
+
 def init_cache(cfg: ModelConfig, batch: int, seq_len: int, *, skip: tuple = ()) -> PyTree:
     """Decode cache sized for a context of ``seq_len`` tokens.
 
     ``skip`` drops pattern positions from the tree — the paged serve path
     keeps full-attention KV in the block pool (``init_pages``) and only the
     O(1)-per-slot state (windowed rings, SSM state, lengths) stays dense."""
+    refuse_serving(cfg)
     cdt = _cdtype(cfg)
     hd = cfg.resolved_head_dim
     cache: dict = {"len": jnp.zeros((), jnp.int32)}
@@ -497,13 +569,9 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
                 new_cache[f"pos{p}"] = new_state
             elif f"pos{p}" in pool:
                 ap = blk["attn"]
-                q = dense(ap["q"], h).reshape(b, 1, cfg.num_heads, hd)
-                k = dense(ap["k"], h).reshape(b, 1, cfg.num_kv_heads, hd)
-                v = dense(ap["v"], h).reshape(b, 1, cfg.num_kv_heads, hd)
                 posv = (jnp.reshape(pos_now, (b,)) if per_slot
                         else jnp.full((b,), pos_now, jnp.int32))
-                q = apply_rope(q, posv[:, None], cfg.rope_theta)
-                k = apply_rope(k, posv[:, None], cfg.rope_theta)
+                q, k, v = _qkv(cfg, ap, h, posv[:, None])
                 pk, pv = pool[f"pos{p}"]["k"], pool[f"pos{p}"]["v"]
                 blk_sz = pk.shape[2]
                 rows = jnp.arange(b)
@@ -546,15 +614,11 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
                 pool[f"pos{p}"] = {"k": pk, "v": pv}
             else:
                 ap = blk["attn"]
-                q = dense(ap["q"], h).reshape(b, 1, cfg.num_heads, hd)
-                k = dense(ap["k"], h).reshape(b, 1, cfg.num_kv_heads, hd)
-                v = dense(ap["v"], h).reshape(b, 1, cfg.num_kv_heads, hd)
                 if per_slot:
                     posb = jnp.reshape(pos_now, (b, 1))
                 else:
                     posb = jnp.full((b, 1), pos_now, jnp.int32)
-                q = apply_rope(q, posb, cfg.rope_theta)
-                k = apply_rope(k, posb, cfg.rope_theta)
+                q, k, v = _qkv(cfg, ap, h, posb)
                 s_c = lcache[f"pos{p}"]["k"].shape[1]
                 slot = jnp.mod(pos_now, s_c)  # ring buffer for windowed layers
                 if per_slot:
@@ -588,7 +652,7 @@ def decode_step(cfg: ModelConfig, params: PyTree, cache: PyTree,
         (jnp.arange(cfg.repeats), blocks, layer_caches),
     )
     x = _norm(cfg, params["final_norm"], x)
-    logits = (x @ params["lm_head"]["kernel"].astype(x.dtype)).astype(jnp.float32)
+    logits = (x @ _head(cfg, params).astype(x.dtype)).astype(jnp.float32)
     if cfg.final_softcap is not None:
         logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
     new_caches["len"] = cache["len"] + 1
@@ -606,7 +670,7 @@ def forward(cfg: ModelConfig, params: PyTree, batch: dict,
     positions = jnp.arange(x.shape[1])[None, :]
     x, _ = _run_stack(cfg, params, x, positions, moe_groups)
     x = _norm(cfg, params["final_norm"], x)
-    logits = (x @ params["lm_head"]["kernel"].astype(x.dtype)).astype(jnp.float32)
+    logits = (x @ _head(cfg, params).astype(x.dtype)).astype(jnp.float32)
     if cfg.final_softcap is not None:
         logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
     return logits
@@ -646,11 +710,7 @@ def prefill_step(cfg: ModelConfig, params: PyTree, batch: dict,
                 h = h_out
             else:
                 ap = blk["attn"]
-                q = dense(ap["q"], h).reshape(b, s, cfg.num_heads, hd)
-                k = dense(ap["k"], h).reshape(b, s, cfg.num_kv_heads, hd)
-                v = dense(ap["v"], h).reshape(b, s, cfg.num_kv_heads, hd)
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
+                q, k, v = _qkv(cfg, ap, h, positions)
                 window = cfg.window if kind == "attn_local" else None
                 # honor cfg.attn_impl exactly like _attn_sublayer: "auto"
                 # picks by length, a pinned impl is obeyed
@@ -684,7 +744,7 @@ def prefill_step(cfg: ModelConfig, params: PyTree, batch: dict,
     x, caches = jax.lax.scan(body, x, _blocks(params, cfg))
     x = _norm(cfg, params["final_norm"], x)
     last = x[:, -1:, :]
-    logits = (last @ params["lm_head"]["kernel"].astype(last.dtype)).astype(jnp.float32)
+    logits = (last @ _head(cfg, params).astype(last.dtype)).astype(jnp.float32)
     if cfg.final_softcap is not None:
         logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
     caches["len"] = jnp.full((), s, jnp.int32)
@@ -771,11 +831,7 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
                 h = h_out
             elif p in paged:  # full attention: prior context from the pool
                 ap = blk["attn"]
-                q = dense(ap["q"], h).reshape(1, c, cfg.num_heads, hd)
-                k = dense(ap["k"], h).reshape(1, c, cfg.num_kv_heads, hd)
-                v = dense(ap["v"], h).reshape(1, c, cfg.num_kv_heads, hd)
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
+                q, k, v = _qkv(cfg, ap, h, positions)
                 pk, pv = pool[f"pos{p}"]["k"], pool[f"pos{p}"]["v"]
                 blk_sz = pk.shape[2]
                 # write-then-read, as in decode: the chunk's blocks are not
@@ -807,11 +863,7 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
                 h = dense(ap["o"], h.reshape(1, c, cfg.num_heads * hd))
             elif kind == "attn_local":  # prior context from the windowed ring
                 ap = blk["attn"]
-                q = dense(ap["q"], h).reshape(1, c, cfg.num_heads, hd)
-                k = dense(ap["k"], h).reshape(1, c, cfg.num_kv_heads, hd)
-                v = dense(ap["v"], h).reshape(1, c, cfg.num_kv_heads, hd)
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
+                q, k, v = _qkv(cfg, ap, h, positions)
                 ring_k, ring_v = lrow[f"pos{p}"]["k"], lrow[f"pos{p}"]["v"]
                 s_c = ring_k.shape[1]
                 prior_pos = offset - s_c + jnp.arange(s_c)  # chronological
@@ -849,7 +901,7 @@ def prefill_chunk(cfg: ModelConfig, params: PyTree, row: PyTree, pages: PyTree,
     )
     x = _norm(cfg, params["final_norm"], x)
     last = x[:, -1:, :]
-    logits = (last @ params["lm_head"]["kernel"].astype(last.dtype)).astype(jnp.float32)
+    logits = (last @ _head(cfg, params).astype(last.dtype)).astype(jnp.float32)
     if cfg.final_softcap is not None:
         logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
     new_row["len"] = row["len"] + c

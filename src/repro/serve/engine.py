@@ -261,6 +261,7 @@ class ServeEngine:
         runlog=None,
         obs_window: int = 16,
     ):
+        tf.refuse_serving(cfg)
         if sampler not in SAMPLERS:
             raise ValueError(f"sampler must be one of {SAMPLERS}, got {sampler!r}")
         # attn_impl="pallas" runs the serving hot loop (paged decode, chunked

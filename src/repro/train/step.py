@@ -110,11 +110,13 @@ def make_train_step(
     ``psn_interpret`` forces the Pallas interpret flag (None = on-TPU
     detection via repro.kernels.default_interpret).
     """
+    counters: tuple[str, ...] = ()
     if loss_fn is None:
         if cfg is None:
             raise ValueError("make_train_step needs cfg or loss_fn")
         base_loss = lambda p, b: tf.loss_fn(cfg, p, b, moe_groups=moe_groups)
         aux = True
+        counters = tf.step_counters(cfg)
     else:
         aux = has_aux if has_aux is not None else False
         base_loss = loss_fn if aux else (lambda p, b: (loss_fn(p, b), {}))
@@ -188,8 +190,10 @@ def make_train_step(
         # fewer parameter-sized loop carry (matters at 405B/1T scale). The
         # estimator statistic Q (moment: sum_j ||m*g_j||^2; exact/gram:
         # sum_i ||g_i||^2) is a scalar per microbatch and stays inside.
+        # the model's step counters (an expert layer's routed rows) ride
+        # the scan too: summed over microbatches, a ``max`` one maxed
         def micro_step(carry, mb):
-            grads_acc, sq_sum, loss_acc = carry
+            grads_acc, sq_sum, loss_acc, counts = carry
             (loss, metrics), grads = grad_fn(state.params, mb)
             grads_acc = jax.tree.map(
                 lambda a, g: a + g.astype(a.dtype), grads_acc, grads
@@ -198,12 +202,15 @@ def make_train_step(
                 sq_sum = sq_sum + _micro_sq_contrib(
                     state.params, mb, grads, micro_global
                 )
-            return (grads_acc, sq_sum, loss_acc + loss), None
+            counts = {k: jnp.maximum(v, metrics[k]) if "max" in k else v + metrics[k]
+                      for k, v in counts.items()}
+            return (grads_acc, sq_sum, loss_acc + loss, counts), None
 
         grads0 = ptu.tree_zeros_like(state.params, dtype=grad_accum_dtype)
         zero = jnp.zeros((), jnp.float32)
-        (grads_acc, sq_sum, loss_sum), _ = jax.lax.scan(
-            micro_step, (grads0, zero, zero), micro
+        counts0 = {k: jnp.zeros((), jnp.int32) for k in counters}
+        (grads_acc, sq_sum, loss_sum, counts), _ = jax.lax.scan(
+            micro_step, (grads0, zero, zero, counts0), micro
         )
         grads = jax.tree.map(lambda g: (g / num_micro), grads_acc)
 
@@ -229,6 +236,7 @@ def make_train_step(
         metrics = {
             "loss": loss_sum / num_micro,
             "grad_norm_sq": ptu.tree_sq_norm(grads),
+            **counts,
         }
         return new_state, metrics
 

@@ -55,7 +55,8 @@ def test_train_step(arch):
     assert float(state2.div_state.sq_norm_sum) > 0
 
 
-@pytest.mark.parametrize("arch", [a for a in ARCH_NAMES if a != "hubert-xlarge"])
+@pytest.mark.parametrize("arch", [a for a in ARCH_NAMES
+                                  if a != "hubert-xlarge" and "conv" not in get_config(a).pattern])
 def test_decode_step(arch):
     cfg = get_config(arch, reduced=True)
     params = tf.init_params(cfg, jax.random.key(0))

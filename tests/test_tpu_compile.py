@@ -110,6 +110,32 @@ def test_paged_decode_compiles(one_chip, layers):
              *([] if layers is None else [((), I32)]))
 
 
+# LFM2-8B-A1B's attention layer: 32 query and 8 KV heads of 64, sequence 8192
+LFM2_QKV = [((1, 8192, 32, 64), BF16), ((1, 8192, 8, 64), BF16), ((1, 8192, 8, 64), BF16)]
+
+
+def test_flash_head_dim_64_compiles(one_chip):
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(_flash(*a).astype(F32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    _compile(grads, one_chip, *LFM2_QKV)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1792), (1792, 2048)], ids=["up", "down"])
+def test_grouped_matmul_compiles(one_chip, k, n):
+    # an expert layer's projections over the sorted buffer of one microbatch
+    # (2 x 8192 tokens x top-4 rows) and 8 held experts: forward, then the
+    # transposed gmm and the tgmm of the backward
+    from repro.kernels.gmm import grouped_matmul
+
+    def grads(x, w, sizes):
+        return jax.grad(lambda x, w: jnp.sum(
+            grouped_matmul(x, w, sizes, interpret=False).astype(F32)), (0, 1))(x, w)
+
+    _compile(grads, one_chip, ((65_536, k), BF16), ((8, k, n), BF16), ((8,), I32))
+
+
 FFN_ACTS = [((1, SEQ, D_MODEL), BF16), ((1, SEQ, D_FF), BF16)]
 
 
