@@ -358,6 +358,15 @@ def _chunk_ce(xc: jax.Array, kernel: jax.Array, tc: jax.Array, softcap):
     return jnp.sum(lse - tgt)
 
 
+# The head's weight gradient is formed by dots of at least this many rows.
+# Summing a dot into the f32 dW costs 8 bytes per element (read and write)
+# against the dot's 2 x rows FLOPs per element: on a v5e (197 TFLOP/s bf16,
+# 819 GB/s) that read-modify-write falls under half the dot's time from about
+# 1,920 rows, while the bf16 dlogits held for one dot stay near rows x V.
+# Rows are counted over the batch as traced (under a plan, the global batch).
+XENT_DW_ROWS = 2048
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def xent_chunked(x: jax.Array, kernel: jax.Array, targets: jax.Array,
                  chunk: int = 512, softcap: float | None = None) -> jax.Array:
@@ -366,8 +375,9 @@ def xent_chunked(x: jax.Array, kernel: jax.Array, targets: jax.Array,
 
     custom_vjp: the naive scan-under-grad would store every chunk's f32
     logits for the backward pass (measured 4e13 HBM bytes/step on qwen2);
-    here the backward RECOMPUTES each chunk's logits and accumulates dW on
-    the fly — residuals are just (x, kernel, targets).
+    here the backward RECOMPUTES each chunk's logits, keeps only their
+    dlogits in x's dtype, and forms dW from groups of chunks of at least
+    ``XENT_DW_ROWS`` rows — residuals are just (x, kernel, targets).
     """
     b, s, d = x.shape
     chunk = min(chunk, s)
@@ -394,8 +404,9 @@ def _xent_bwd(chunk, softcap, res, g):
     chunk = min(chunk, s)
     v = kernel.shape[1]
     scale = g / (b * s)
+    per_group = min(nc, -(-XENT_DW_ROWS // (b * chunk)))
 
-    def body(dw_acc, i):
+    def chunk_grads(_, i):
         xc = jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk, axis=1)
         tc = jax.lax.dynamic_slice_in_dim(targets, i * chunk, chunk, axis=1)
         logits = (xc @ kernel.astype(xc.dtype)).astype(jnp.float32)
@@ -409,13 +420,32 @@ def _xent_bwd(chunk, softcap, res, g):
             dlogits = dlogits * (1.0 - capped * capped)
         dlogits = (dlogits * scale).astype(x.dtype)
         dxc = dlogits @ kernel.astype(x.dtype).T
-        dw_acc = dw_acc + jnp.einsum(
-            "bcd,bcv->dv", xc.astype(jnp.float32), dlogits.astype(jnp.float32)
-        )
-        return dw_acc, dxc
+        return None, (dxc, dlogits)
 
-    dw0 = jnp.zeros(kernel.shape, jnp.float32)
-    dw, dx_chunks = jax.lax.scan(body, dw0, jnp.arange(nc))
+    def group(first, n):
+        """dx of chunks [first, first + n) and their one dot's share of dW."""
+        _, (dxc, dlogits) = jax.lax.scan(chunk_grads, None, first + jnp.arange(n))
+        xg = jax.lax.dynamic_slice_in_dim(x, first * chunk, n * chunk, axis=1)
+        xg = jnp.moveaxis(xg.reshape(b, n, chunk, d), 1, 0)
+        with jax.named_scope("xent_dw"):
+            dw = jnp.einsum("nbcd,nbcv->dv", xg, dlogits,
+                            preferred_element_type=jnp.float32)
+        return dxc, dw
+
+    full, rest = divmod(nc, per_group)
+    if full == 1:
+        dx_chunks, dw = group(0, per_group)
+    else:
+        def body(dw_acc, j):
+            dxc, dw = group(j * per_group, per_group)
+            return dw_acc + dw, dxc
+
+        dw, dx_chunks = jax.lax.scan(body, jnp.zeros(kernel.shape, jnp.float32),
+                                     jnp.arange(full))
+        dx_chunks = dx_chunks.reshape(full * per_group, b, chunk, d)
+    if rest:
+        dxc, dw_rest = group(full * per_group, rest)
+        dx_chunks, dw = jnp.concatenate([dx_chunks, dxc]), dw + dw_rest
     dx = jnp.moveaxis(dx_chunks, 0, 1).reshape(b, s, d)
     return dx, dw.astype(kernel.dtype), None
 

@@ -44,22 +44,34 @@ class TestFlashAttention:
 
 
 class TestChunkedXent:
-    def test_matches_naive(self):
-        x = jax.random.normal(jax.random.key(4), (2, 32, 16))
+    # (batch, seq, chunk, softcap): the backward forms dW from groups of
+    # ceil(XENT_DW_ROWS / (batch x chunk)) chunks, at most every chunk
+    @pytest.mark.parametrize("b,s,chunk,softcap", [
+        (2, 32, 8, None),                          # one group
+        (2, 4096, 16, None),                       # 4 groups of 64 chunks
+        (1, 16 * (2 * 128 + 3), 16, None),         # 2 groups of 128 and 3 chunks
+        (2, 4096, 16, 30.0),                       # several groups, softcapped
+    ], ids=["one_group", "groups", "uneven_groups", "softcap"])
+    def test_matches_naive(self, b, s, chunk, softcap):
+        x = jax.random.normal(jax.random.key(4), (b, s, 16))
         kern = jax.random.normal(jax.random.key(5), (16, 51)) * 0.1
-        tgt = jax.random.randint(jax.random.key(6), (2, 32), 0, 51)
+        tgt = jax.random.randint(jax.random.key(6), (b, s), 0, 51)
 
         def naive(x, k):
             logits = (x @ k).astype(jnp.float32)
+            if softcap is not None:
+                logits = jnp.tanh(logits / softcap) * softcap
             lse = jax.scipy.special.logsumexp(logits, -1)
             t = jnp.take_along_axis(logits, tgt[..., None], -1)[..., 0]
             return jnp.mean(lse - t)
 
-        l1 = naive(x, kern)
-        l2 = tf.xent_chunked(x, kern, tgt, 8, None)
-        np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
+        def chunked(x, k):
+            return tf.xent_chunked(x, k, tgt, chunk, softcap)
+
+        np.testing.assert_allclose(float(naive(x, kern)), float(chunked(x, kern)),
+                                   rtol=1e-6)
         g1 = jax.grad(naive, argnums=(0, 1))(x, kern)
-        g2 = jax.grad(lambda a, b: tf.xent_chunked(a, b, tgt, 8, None), argnums=(0, 1))(x, kern)
+        g2 = jax.grad(chunked, argnums=(0, 1))(x, kern)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 

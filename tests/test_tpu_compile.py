@@ -17,6 +17,7 @@ workers import this file would break the other workers.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,11 +52,14 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, one_chip, *shapes):
+def _compiled_text(fn, one_chip, *shapes) -> str:
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in shapes]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _compile(fn, one_chip, *shapes):
+    assert "tpu_custom_call" in _compiled_text(fn, one_chip, *shapes)
 
 
 QKV = [((1, SEQ, HEADS, HD), BF16), ((1, SEQ, KV_HEADS, HD), BF16),
@@ -162,6 +166,25 @@ def test_psgn_fused_compiles(one_chip):
 def test_quantize_int8_compiles(one_chip):
     _compile(lambda x: quant.quantize_int8(x, interpret=False), one_chip,
              ((D_MODEL, D_FF), F32))
+
+
+def test_head_backward_carries_no_f32_weight_gradient(one_chip):
+    """The LM head's backward at Yi widths (vocab 64000, chunks of 512):
+    the chunk loop keeps each chunk's bf16 dlogits and the weight gradient
+    is one dot after it, so no loop carries an f32 accumulator of the
+    head's shape through a read-modify-write at every chunk."""
+    from repro.models import transformer as tf
+
+    def grads(x, kernel, targets):
+        return jax.grad(lambda a, k: tf.xent_chunked(a, k, targets, 512, None),
+                        argnums=(0, 1))(x, kernel)
+
+    text = _compiled_text(grads, one_chip, ((1, SEQ, D_MODEL), BF16),
+                          ((D_MODEL, 64_000), F32), ((1, SEQ), I32))
+    carries = [line.split(" while(")[0] for line in text.splitlines()
+               if re.search(r" while\(", line)]
+    assert carries, "the chunk loop is gone"
+    assert not any(f"f32[{D_MODEL},64000]" in c for c in carries), carries
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
